@@ -27,6 +27,7 @@ from benchmarks import (
     tier_portfolio,
     variant_grid,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = {
     "fig2": fig2_model_pool.run,
@@ -49,6 +50,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, choices=list(BENCHES))
     args = ap.parse_args()
+    enable_compile_cache()
 
     t0 = time.perf_counter()
     print("bench,metric,value,claim,status")

@@ -167,8 +167,7 @@ def _jax_bench() -> dict:
         )
         statics["policy"] = pol.default_params()
         runner = je._get_runner("portfolio")
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             scan_wall = float("inf")
             for _ in range(3):
                 t = time.perf_counter()
@@ -228,7 +227,6 @@ def _fleet_pair(A: int, repeats: int) -> dict:
     cross-box absolute-throughput jitter that keeps the NumPy-vs-JAX
     rows report-only; the two ledgers are asserted equivalent first."""
     import jax
-    from jax.experimental import enable_x64
 
     from repro.core.sim import jax_engine as je
     from repro.core.workloads import SCENARIO_ZOO
@@ -249,7 +247,7 @@ def _fleet_pair(A: int, repeats: int) -> dict:
         )
         statics["policy"] = pol.default_params()
         runner = je._get_runner("portfolio", flavor=flavor)
-        with enable_x64():
+        with jax.enable_x64(True):
             t = time.perf_counter()
             out = jax.block_until_ready(runner(statics, state0, xs))
             first = time.perf_counter() - t

@@ -124,7 +124,6 @@ def _speedup_bench() -> dict:
     build and compile are reported separately, exactly like the
     ``sim_throughput`` scan rows."""
     import jax
-    from jax.experimental import enable_x64
 
     from repro.core.sim import jax_engine as je
 
@@ -151,7 +150,7 @@ def _speedup_bench() -> dict:
     )
     statics["policy"] = pol.default_params()
     runner = je._get_runner("infaas_variant", variants=True)
-    with enable_x64():
+    with jax.enable_x64(True):
         t = time.perf_counter()
         out = jax.block_until_ready(runner(statics, state0, xs))
         first = time.perf_counter() - t

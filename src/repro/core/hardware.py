@@ -1,8 +1,10 @@
-"""TPU v5e machine model — the single source of hardware truth.
+"""TPU v5e machine model — the simulator's hardware inputs.
 
-Every latency/cost number in the serving layer and every roofline term in
-the benchmarks is derived from these constants; nothing is wall-clocked on
-this CPU-only container.
+Every latency/cost number the simulator's profiles carry and every
+roofline term in the benchmarks is derived from these constants.  They
+are planning numbers (published peaks and assumed achievable fractions),
+not measurements: what the chip actually does is measured by running on
+it (``chip_smoke.py`` is the smallest such run).
 """
 from __future__ import annotations
 

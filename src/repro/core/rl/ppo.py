@@ -218,8 +218,7 @@ def collect_rollouts_jax(env: PoolServingEnv, params, key, *,
         "rate_scale": cfg.rate_scale,
         "fleet_scale": cfg.fleet_scale,
     }
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         out = jax.tree.map(
             np.asarray,
             jax_engine._get_runner("rl_sample", mode="stack",
@@ -307,8 +306,7 @@ def collect_rollouts_jax_zoo(env: PoolServingEnv, params, key) -> dict:
         "rate_scale": cfg.rate_scale,
         "fleet_scale": cfg.fleet_scale,
     }] * S)
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         out = jax.tree.map(
             np.asarray,
             jax_engine._get_runner("rl_sample", mode="stack", batched=True,

@@ -67,7 +67,7 @@ fast without changing results:
   materialized host-side, bit-identical to the streams the NumPy tiers
   consume, and fed to the scan as per-tick inputs.
 
-Everything runs under ``jax.experimental.enable_x64`` (float64, like
+Everything runs under ``jax.enable_x64(True)`` (float64, like
 the NumPy engine) without flipping the global flag — the float32 PPO
 training stack is untouched.  Policies are in-scan twins of the
 vectorized schedulers (:data:`JAX_POLICIES`); their parameters ride in
@@ -83,7 +83,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core.hardware import PRICING, FleetPricing
 from repro.core.load_monitor import (
@@ -1412,8 +1411,6 @@ def _get_sharded_runner(policy: str, mesh, mode: str = "sum",
     "cells" axis maps onto the mesh axis through the standard
     :mod:`repro.distributed.sharding` rules so the spec derivation is
     the same one model code uses."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.distributed.sharding import AxisRules, logical_to_spec
 
     ndev = mesh.devices.size
@@ -1431,13 +1428,13 @@ def _get_sharded_runner(policy: str, mesh, mode: str = "sum",
         rules = AxisRules(mesh, {"cells": mesh.axis_names[0]})
         cell = logical_to_spec(("cells",), rules)
         rep = logical_to_spec((), rules)
-        # check_rep=False: the binomial inverse-CDF lax.while_loop has no
+        # check_vma=False: the binomial inverse-CDF lax.while_loop has no
         # shard_map replication rule; every input/output spec is explicit
         # here so the check adds nothing.
-        fn = shard_map(
+        fn = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(rep, cell, cell, cell), out_specs=cell,
-            check_rep=False,
+            check_vma=False,
         )
         _RUNNERS[key] = jax.jit(fn)
     return _RUNNERS[key]
@@ -1638,7 +1635,7 @@ def run_scenario(
     variants = "var_smult" in statics
     statics["policy"] = pol.default_params() if params is None else params
     mode = "stack" if record_trajectory else "sum"
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _tree_to_host(
             _get_runner(policy, mode=mode, variants=variants)(
                 statics, state0, xs
@@ -1677,10 +1674,13 @@ def run_grid(
     cell.
 
     With more than one device the cell axis is sharded across them via
-    ``shard_map`` (``sharded=None`` auto-enables when the cell count
-    divides evenly; ``True`` requires it, ``False`` forces the single
-    dispatch).  Cells never communicate, so the sharded and unsharded
-    paths compute identical cells."""
+    ``shard_map`` (``sharded=None`` shards whenever there are several
+    devices; ``True`` requires them, ``False`` forces the single
+    dispatch).  A cell count that the device count does not divide is
+    padded with copies of cell 0, which are dropped from the result.
+    Cells never communicate, so the sharded and unsharded paths compute
+    identical cells.  Each cell's dict also says over how many
+    ``"devices"`` the grid's output was spread."""
     from repro.distributed.sharding import device_mesh
 
     arrivals_batch = np.asarray(arrivals_batch, dtype=np.float64)
@@ -1722,21 +1722,27 @@ def run_grid(
         params_batch = [pol.default_params() for _ in range(B)]
     policy_b = _tree_stack(list(params_batch))
     mesh = device_mesh()
-    use_shard = (
-        mesh is not None and B % mesh.devices.size == 0
-        if sharded is None else sharded
-    )
+    use_shard = mesh is not None if sharded is None else sharded
     if use_shard:
-        assert mesh is not None and B % mesh.devices.size == 0, (
-            f"sharded run_grid needs the cell count ({B}) to divide the "
-            f"device count ({1 if mesh is None else mesh.devices.size})"
+        assert mesh is not None, "sharded run_grid needs more than one device"
+        pad = -B % mesh.devices.size
+        state0_b, xs_b, policy_b = (
+            jax.tree.map(
+                lambda a: np.concatenate([a, np.repeat(a[:1], pad, axis=0)]),
+                tree,
+            )
+            for tree in (state0_b, xs_b, policy_b)
         )
         runner = _get_sharded_runner(policy, mesh, variants=variants)
     else:
         runner = _get_runner(policy, batched=True, variants=variants)
-    with enable_x64():
-        out = _tree_to_host(runner(statics, policy_b, state0_b, xs_b))
-    note_runner_use(policy, batched=True, variants=variants)
+    with jax.enable_x64(True):
+        out = runner(statics, policy_b, state0_b, xs_b)
+        devices = min(len(leaf.sharding.device_set) for leaf in jax.tree.leaves(out))
+        out = _tree_to_host(out)
+    if not use_shard:
+        note_runner_use(policy, batched=True, variants=variants)
     return [
-        _assemble(_tree_index(out, i), arrivals_batch[i]) for i in range(B)
+        {**_assemble(_tree_index(out, i), arrivals_batch[i]), "devices": devices}
+        for i in range(B)
     ]

@@ -4,8 +4,11 @@ Tiling: grid = (batch, q_heads, Sq/BQ, Sk/BK); the KV axis is the
 innermost (sequential on TPU) grid dimension, so the online-softmax
 running statistics (m, l) and the f32 accumulator live in VMEM scratch
 carried across KV steps.  Blocks are MXU-aligned (128x128 by default).
-GQA is handled in the index maps (query head h reads KV head h // group);
-causal and sliding-window masks are applied from block-relative position
+The wrapper lays q/k/v out heads-major, (B, heads, S, hd), so every block
+is a (rows, hd) tile whose last two dimensions meet the TPU block rule
+(rows a multiple of 8, hd the full head dim).  GQA is handled in the
+index maps (query head h reads KV head h // group); causal and
+sliding-window masks are applied from block-relative position
 arithmetic, so no (Sq, Sk) mask tensor ever materializes.
 
 Validated on CPU with ``interpret=True`` against ``ref.mha_reference``.
@@ -20,6 +23,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _fa_kernel(
@@ -44,12 +48,13 @@ def _fa_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)        # (BQ, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)        # (BK, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)        # (BK, hd)
+    q = q_ref[...].astype(jnp.float32)                # (BQ, hd)
+    k = k_ref[...].astype(jnp.float32)                # (BK, hd)
+    v = v_ref[...].astype(jnp.float32)                # (BK, hd)
 
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, k, (((1,), (1,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32,
     ) * scale                                         # (BQ, BK)
 
     qpos = q_offset + iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -61,15 +66,15 @@ def _fa_kernel(
         mask &= kpos > qpos - window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    m_prev = m_ref[...]                               # (BQ, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(mask, p, 0.0)
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
 
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=jnp.float32,
     )
     m_ref[...] = m_new
 
@@ -77,7 +82,7 @@ def _fa_kernel(
     def _finalize():
         l = l_ref[...]
         safe = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0, :, 0, :] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -115,6 +120,8 @@ def flash_attention(
         k = jnp.pad(k, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
 
+    # heads-major: each block is a (rows, hd) tile of one (batch, head)
+    q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))
     grid = (b, nq, sq_p // bq, sk_p // bk)
 
     out = pl.pallas_call(
@@ -125,21 +132,25 @@ def flash_attention(
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda b_, h, iq, ik: (b_, iq, h, 0)),
+            pl.BlockSpec((None, None, bq, hd), lambda b_, h, iq, ik: (b_, h, iq, 0)),
             pl.BlockSpec(
-                (1, bk, 1, hd), lambda b_, h, iq, ik, g=group: (b_, ik, h // g, 0)
+                (None, None, bk, hd),
+                lambda b_, h, iq, ik, g=group: (b_, h // g, ik, 0),
             ),
             pl.BlockSpec(
-                (1, bk, 1, hd), lambda b_, h, iq, ik, g=group: (b_, ik, h // g, 0)
+                (None, None, bk, hd),
+                lambda b_, h, iq, ik, g=group: (b_, h // g, ik, 0),
             ),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd), lambda b_, h, iq, ik: (b_, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, nq, hd), q.dtype),
+        out_specs=pl.BlockSpec(
+            (None, None, bq, hd), lambda b_, h, iq, ik: (b_, h, iq, 0)
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, nq, sq_p, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, hd), jnp.float32),   # acc
-            pltpu.VMEM((bq,), jnp.float32),      # m (running max)
-            pltpu.VMEM((bq,), jnp.float32),      # l (running sum)
+            pltpu.VMEM((bq, 1), jnp.float32),    # m (running max)
+            pltpu.VMEM((bq, 1), jnp.float32),    # l (running sum)
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :sq]
+    return jnp.swapaxes(out, 1, 2)[:, :sq]

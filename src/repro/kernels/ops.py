@@ -1,11 +1,14 @@
 """Jit'd dispatch wrappers around the compute hot-spots.
 
 ``impl`` selects the execution path:
-  * ``"xla"``               — pure-jnp (ref.py), the default; used by CPU
-                               tests and by the dry-run lowering.
-  * ``"pallas"``            — the Pallas TPU kernel (TARGET hardware).
+  * ``"pallas"``            — the Pallas TPU kernel; the default on a TPU.
+  * ``"xla"``               — pure-jnp (ref.py); the default everywhere
+                               else (CPU tests, the dry-run lowering).
   * ``"pallas_interpret"``  — the same kernel body interpreted on CPU
-                               (correctness validation in this container).
+                               (correctness validation off the chip).
+
+The default is read from the platform at trace time.  A kernel that the
+chip's compiler refuses is an error: nothing falls back to the reference.
 """
 from __future__ import annotations
 
@@ -16,21 +19,18 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 
-_DEFAULT_IMPL = "xla"
-
-
-def set_default_impl(impl: str) -> None:
-    global _DEFAULT_IMPL
-    assert impl in ("xla", "pallas", "pallas_interpret"), impl
-    _DEFAULT_IMPL = impl
+IMPLS = ("xla", "pallas", "pallas_interpret")
 
 
 def default_impl() -> str:
-    return _DEFAULT_IMPL
+    """The kernel path this process runs when a caller names none."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def _resolve(impl: Optional[str]) -> str:
-    return impl or _DEFAULT_IMPL
+    impl = impl or default_impl()
+    assert impl in IMPLS, impl
+    return impl
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
 
 from repro.configs.registry import ModelConfig
 from repro.distributed.sharding import AxisRules
@@ -20,11 +21,13 @@ from repro.distributed.sharding import AxisRules
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_test_mesh(shape: Tuple[int, ...] = (1, 1), axes=("data", "model")):
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: Tuple[int, ...] = (1, 1), axes=("data", "model")):
+    # Auto axes: the model code places activations with
+    # with_sharding_constraint, which Explicit axes refuse
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_rules(

@@ -1,9 +1,11 @@
 """Serving driver.
 
-Runs the continuous-batching engine for any registered architecture.
-On this CPU container use ``--reduced`` (the smoke variant); on real
-hardware the same driver serves the full config under the production
-mesh shardings from ``launch/specs.py``.
+Runs the continuous-batching engine for any registered architecture on
+the first device JAX finds.  On a TPU the attention kernels are the
+Pallas ones; elsewhere their jnp references run.  ``--reduced`` serves
+the small smoke variant, the size for a CPU; the full config of a model
+that fits one chip (qwen1.5-0.5b, as in ``chip_smoke.py``) serves
+unsharded on one v5e.
 
   PYTHONPATH=src python -m repro.launch.serve --arch llama3-8b --reduced \
       --requests 16 --slots 4 --max-new 8

@@ -28,16 +28,6 @@ from repro.configs.registry import ModelConfig
 from repro.distributed.sharding import current_rules, shard
 from repro.models.params import boxed_normal
 
-# shard_map graduated from jax.experimental (and renamed check_rep ->
-# check_vma) in newer jax; support both spellings
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:                                     # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
-
 
 def init_moe(key, cfg: ModelConfig, dtype) -> dict:
     d = cfg.d_model
@@ -222,12 +212,12 @@ def moe_ep_a2a(cfg: ModelConfig, p: dict, x: jax.Array):
     x_spec = P(batch_axes if batch_axes else None, ep_axis, None)
     w_spec = P(ep_axis, None, None)
     out_specs = (x_spec, P())
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
         out_specs=out_specs,
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(x, p["router"], p["wi_gate"], p["wi_up"], p["wo"])
     return y, aux
 

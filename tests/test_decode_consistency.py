@@ -7,19 +7,10 @@ import pytest
 from repro.configs import get_config
 from repro.models import model as model_lib
 
-# the two MoE members xfail: pre-existing seed failure — their decode-step
-# logits diverge from the full forward (err ~1.1 vs 5e-3 tol), a routing
-# mismatch between the batched prefill and single-token decode paths
-_MOE_XFAIL = pytest.mark.xfail(
-    reason="seed-era MoE prefill/decode routing divergence (fails at seed commit)",
-    strict=True,
-)
 DECODE_ARCHS = [
     "llama3-8b", "qwen1.5-0.5b", "qwen2-72b", "minicpm-2b",
-    pytest.param("phi3.5-moe-42b-a6.6b", marks=_MOE_XFAIL),
-    "rwkv6-1.6b", "recurrentgemma-9b",
-    "whisper-small",
-    pytest.param("kimi-k2-1t-a32b", marks=_MOE_XFAIL),
+    "phi3.5-moe-42b-a6.6b", "rwkv6-1.6b", "recurrentgemma-9b",
+    "whisper-small", "kimi-k2-1t-a32b",
 ]
 
 

@@ -321,9 +321,8 @@ def _scripted_parity(arr, wl, catalog, np_policy, jax_apply, seed=0):
         lazy_rings=False,
     )
     statics["policy"] = {}
-    from jax.experimental import enable_x64
     run = jax.jit(je.make_runner(jax_apply, "sum", variants=True))
-    with enable_x64():
+    with jax.enable_x64(True):
         out = jax.tree.map(np.asarray, run(statics, state0, xs))
     res = je._assemble(out, np.asarray(arr, dtype=np.float64))
     raw_np, raw_jx = _raw_ledger_np(sim.res), _raw_ledger_jx(res)
@@ -523,13 +522,12 @@ def test_donation_safety_and_flavor_parity():
     aliases only the fresh device staging buffers, never the caller's
     NumPy arrays — and (b) does not drift from the legacy flavor
     (eager ring clips, host-fed EWMA, stacked post-scan reduction)."""
-    from jax.experimental import enable_x64
 
     A, T = 8, 300
     wl = _workload(A)
     arr = SCENARIO_ZOO["mmpp_bursts"].build(A, duration_s=T, seed=5)
     pol = je.JAX_POLICIES["portfolio"]
-    with enable_x64():
+    with jax.enable_x64(True):
         statics, state0, xs = je.build_sim_inputs(
             arr, wl, seed=3, needs_stats=pol.needs_stats,
             needs_key=pol.needs_key,
@@ -597,14 +595,13 @@ def test_smoke_grid_matches_run_scenario():
 def test_binomial_jnp_matches_numpy():
     """The in-scan inverse-CDF binomial is the NumPy twin's, bit for
     bit, across the (n, p, u) grid both engines draw from."""
-    from jax.experimental import enable_x64
 
     rng = np.random.default_rng(0)
     n = rng.integers(0, BINOMIAL_KMAX + 10, size=200)
     u = rng.random(200)
     for p in (0.0, 1e-4, 0.01, 0.3, 1.0):
         want = binomial_from_uniform(n, p, u)
-        with enable_x64():      # the scan always runs in x64
+        with jax.enable_x64(True):      # the scan always runs in x64
             got = np.asarray(je.binomial_from_uniform_jnp(
                 np.asarray(n), float(p), np.asarray(u)
             ))
@@ -735,10 +732,19 @@ sh = je.run_grid(arrs, wl, "portfolio", seeds=seeds, sharded=True)
 un = je.run_grid(arrs, wl, "portfolio", seeds=seeds, sharded=False)
 for i in range(len(names)):
     assert sh[i]["summary"] == un[i]["summary"], (i, sh[i], un[i])
+assert all(c["devices"] == 2 for c in sh) and all(c["devices"] == 1 for c in un)
 # auto mode: 2 cells % 2 devices == 0 -> sharded path, same cells
 auto = je.run_grid(arrs, wl, "portfolio", seeds=seeds)
 for i in range(len(names)):
     assert auto[i]["summary"] == un[i]["summary"], i
+# 3 cells on 2 devices: padded to 4, still sharded, padding dropped
+arrs3 = np.concatenate([arrs, arrs[1:]])
+seeds3 = seeds + [7]
+sh3 = je.run_grid(arrs3, wl, "portfolio", seeds=seeds3)
+un3 = je.run_grid(arrs3, wl, "portfolio", seeds=seeds3, sharded=False)
+assert len(sh3) == 3 and all(c["devices"] == 2 for c in sh3)
+for i in range(3):
+    assert sh3[i]["summary"] == un3[i]["summary"], (i, sh3[i], un3[i])
 print("SHARDED_PARITY_OK")
 """
     env = dict(os.environ)

@@ -72,11 +72,12 @@ def test_ep_a2a_falls_back_without_rules():
 def test_ep_a2a_single_device_mesh():
     """shard_map path on a 1x1 mesh must equal the dense oracle."""
     from repro.distributed.sharding import AxisRules, axis_rules
+    from repro.launch.mesh import make_mesh
 
     cfg = _cfg(e=4, k=2)
     p = _params(cfg)
     x = jax.random.normal(jax.random.key(5), (2, 8, cfg.d_model))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh()
     rules = AxisRules(mesh=mesh, rules={"experts": "model", "batch": ("data",)})
     with mesh, axis_rules(rules):
         y_ep, _ = moe_lib.moe_ep_a2a(cfg, p, x)

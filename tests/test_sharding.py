@@ -8,14 +8,11 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config
 from repro.configs.registry import InputShape
 from repro.distributed.sharding import AxisRules, axis_rules, logical_to_spec
-from repro.launch.mesh import make_rules
+from repro.launch.mesh import make_mesh, make_rules
 from repro.launch.specs import build_step
 
-# AbstractMesh takes (name, size) pairs since jax 0.4.36
-PROD_MESH = jax.sharding.AbstractMesh((("data", 16), ("model", 16)))
-POD_MESH = jax.sharding.AbstractMesh(
-    (("pod", 2), ("data", 16), ("model", 16))
-)
+PROD_MESH = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
+POD_MESH = jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_logical_to_spec_basic():
@@ -87,7 +84,7 @@ SMALL_SHAPES = {
 @pytest.mark.parametrize("kind", ["train", "prefill", "decode"])
 def test_lower_compile_small_mesh(arch, kind):
     cfg = get_config(arch).reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh()
     shape = SMALL_SHAPES[kind]
     step, args, in_shardings, rules, _donate = build_step(
         cfg, shape, mesh, param_dtype=jnp.float32)
@@ -100,7 +97,7 @@ def test_lower_long_context_window(arch="llama3-8b"):
     """long_500k on a dense arch must lower through the sliding-window
     variant (ring cache shorter than the sequence)."""
     cfg = get_config(arch).reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh()
     step, args, in_shardings, rules, _donate = build_step(
         cfg, SMALL_SHAPES["long"], mesh, param_dtype=jnp.float32
     )

@@ -692,57 +692,59 @@ def _tick(state: SimState, xs: dict, st: dict, policy_apply,
     tick at the NEW rate), new requests enter the depth-1 pipeline after
     the pop, and serving / burst billing / accuracy / chip accounting
     all gather at the POST-pop variant."""
-    t = xs["t"]
-    rate = xs["rate"]
-    A = rate.shape[0]
-    if ewma_in_carry:
-        # first observe seeds the EWMA with the raw rates (seen == 0)
-        ewma = jnp.where(
-            t == 0, rate,
-            _EWMA_ALPHA * rate + (1.0 - _EWMA_ALPHA) * state.ewma,
-        )
-    else:
-        ewma = xs["ewma"]
+    with jax.named_scope("scan.observe"):
+        t = xs["t"]
+        rate = xs["rate"]
+        A = rate.shape[0]
+        if ewma_in_carry:
+            # first observe seeds the EWMA with the raw rates (seen == 0)
+            ewma = jnp.where(
+                t == 0, rate,
+                _EWMA_ALPHA * rate + (1.0 - _EWMA_ALPHA) * state.ewma,
+            )
+        else:
+            ewma = xs["ewma"]
 
-    # ---- admit (observe_pool): age the queues, push this tick (new
-    # arrivals land in the newest bucket: only the total prefix) -------
-    qs_buf = _age_queue(state.qs_buf)
-    qr_buf = _age_queue(state.qr_buf)
-    n_strict = rate * st["strict_frac"]
-    n_relaxed = rate - n_strict
-    qs_buf = qs_buf.at[:, -1].add(n_strict)
-    qr_buf = qr_buf.at[:, -1].add(n_relaxed)
-    qs_tot = qs_buf[:, -1]
-    qr_tot = qr_buf[:, -1]
+        # ---- admit (observe_pool): age the queues, push this tick (new
+        # arrivals land in the newest bucket: only the total prefix) -------
+        qs_buf = _age_queue(state.qs_buf)
+        qr_buf = _age_queue(state.qr_buf)
+        n_strict = rate * st["strict_frac"]
+        n_relaxed = rate - n_strict
+        qs_buf = qs_buf.at[:, -1].add(n_strict)
+        qr_buf = qr_buf.at[:, -1].add(n_relaxed)
+        qs_tot = qs_buf[:, -1]
+        qr_tot = qr_buf[:, -1]
 
     # ---- variant observation (pre-pop, like the NumPy observe_pool:
     # due swaps have NOT landed yet, so ratios and throughput gather at
     # the carried active variant; catalog-free every entry aliases a
     # read-only static and no gather is traced) ------------------------
     if variants:
-        v_cur = state.var_cur
-        v_pend = state.var_pending
-        smult_cur = _gather_v(st["var_smult"], v_cur)
-        v_up = jnp.minimum(v_cur + 1, st["var_n"] - 1)
-        v_dn = jnp.maximum(v_cur - 1, 0)
-        vobs = {
-            "throughput": st["thr"] * smult_cur,
-            "active_variant": v_cur,
-            "n_variants": st["var_n"],
-            "accuracy": _gather_v(st["var_acc"], v_cur),
-            "accuracy_floor": st["acc_floor"],
-            "variant_lo": st["var_lo"],
-            "variant_cheapest": st["var_cheapest"],
-            "variant_in_flight": v_pend >= 0,
-            "variant_up_ratio": _gather_v(st["var_smult"], v_up) / smult_cur,
-            "variant_down_ratio": _gather_v(st["var_smult"], v_dn) / smult_cur,
-            "variant_pending_ratio": jnp.where(
-                v_pend >= 0,
-                _gather_v(st["var_smult"], jnp.maximum(v_pend, 0)) / smult_cur,
-                1.0,
-            ),
-            "variant_last_move": state.var_last_move,
-        }
+        with jax.named_scope("scan.variants"):
+            v_cur = state.var_cur
+            v_pend = state.var_pending
+            smult_cur = _gather_v(st["var_smult"], v_cur)
+            v_up = jnp.minimum(v_cur + 1, st["var_n"] - 1)
+            v_dn = jnp.maximum(v_cur - 1, 0)
+            vobs = {
+                "throughput": st["thr"] * smult_cur,
+                "active_variant": v_cur,
+                "n_variants": st["var_n"],
+                "accuracy": _gather_v(st["var_acc"], v_cur),
+                "accuracy_floor": st["acc_floor"],
+                "variant_lo": st["var_lo"],
+                "variant_cheapest": st["var_cheapest"],
+                "variant_in_flight": v_pend >= 0,
+                "variant_up_ratio": _gather_v(st["var_smult"], v_up) / smult_cur,
+                "variant_down_ratio": _gather_v(st["var_smult"], v_dn) / smult_cur,
+                "variant_pending_ratio": jnp.where(
+                    v_pend >= 0,
+                    _gather_v(st["var_smult"], jnp.maximum(v_pend, 0)) / smult_cur,
+                    1.0,
+                ),
+                "variant_last_move": state.var_last_move,
+            }
     else:
         vobs = {
             "throughput": st["thr"],
@@ -762,242 +764,248 @@ def _tick(state: SimState, xs: dict, st: dict, policy_apply,
     # ---- observe: the traced PoolObs (pre-provision state, like the
     # NumPy observe_pool; idle-tier fields equal the static zeros the
     # NumPy engine serves because a dead tier's state IS zero) ---------
-    obs = {
-        "rate": rate,
-        "ewma_rate": ewma,
-        "peak_to_median": xs["p2m"],
-        "queue_len": qs_tot + qr_tot,
-        "queue_strict": qs_tot,
-        "queue_relaxed": qr_tot,
-        "n_active": state.res_active,
-        "n_pending": (state.res_cum - state.res_mat).astype(jnp.int64),
-        "n_spot": state.spot_active,
-        "n_spot_pending": (state.spot_cum - state.spot_mat).astype(jnp.int64),
-        "n_harvest": state.harv_active,
-        "n_harvest_pending": (state.harv_cum - state.harv_mat).astype(jnp.int64),
-        "n_remote": state.rem_active,
-        "n_remote_pending": (state.rem_cum - state.rem_mat).astype(jnp.int64),
-        "utilization": state.last_util,
-        "last_violations": state.last_viol,
-        "harvest_level": jnp.broadcast_to(xs["h_lev_obs"], (A,)),
-        "harvest_ceiling": jnp.broadcast_to(xs["h_ceil_obs"], (A,)),
-        "spot_reclaim_risk": st["risk"],
-        "tick": t,
-        "prev_rate": state.prev_rate,
-        **vobs,
-    }
-    acts, extras = policy_apply(st["policy"], obs, xs.get("key"))
+    with jax.named_scope("scan.observe"):
+        obs = {
+            "rate": rate,
+            "ewma_rate": ewma,
+            "peak_to_median": xs["p2m"],
+            "queue_len": qs_tot + qr_tot,
+            "queue_strict": qs_tot,
+            "queue_relaxed": qr_tot,
+            "n_active": state.res_active,
+            "n_pending": (state.res_cum - state.res_mat).astype(jnp.int64),
+            "n_spot": state.spot_active,
+            "n_spot_pending": (state.spot_cum - state.spot_mat).astype(jnp.int64),
+            "n_harvest": state.harv_active,
+            "n_harvest_pending": (state.harv_cum - state.harv_mat).astype(jnp.int64),
+            "n_remote": state.rem_active,
+            "n_remote_pending": (state.rem_cum - state.rem_mat).astype(jnp.int64),
+            "utilization": state.last_util,
+            "last_violations": state.last_viol,
+            "harvest_level": jnp.broadcast_to(xs["h_lev_obs"], (A,)),
+            "harvest_ceiling": jnp.broadcast_to(xs["h_ceil_obs"], (A,)),
+            "spot_reclaim_risk": st["risk"],
+            "tick": t,
+            "prev_rate": state.prev_rate,
+            **vobs,
+        }
+    with jax.named_scope("scan.policy"):
+        acts, extras = policy_apply(st["policy"], obs, xs.get("key"))
 
     # ---- variant swaps (ServingSim._step order): pop matured swaps
     # BEFORE provisioning/serving — the arch serves this tick at the new
     # rate — then enqueue this tick's requests into the depth-1 slot
     # (cancel-newest = one overwrite, exactly SwapPipeline.request) ----
     if variants:
-        done = (v_pend >= 0) & (state.var_ready <= t)
-        v_cur = jnp.where(done, v_pend, v_cur)
-        v_pend = jnp.where(done, -1, v_pend)
-        swaps = done.sum()
-        # POST-pop effective serving state (what _refresh_variant_state
-        # caches on the NumPy engine): serve, bill burst invocations and
-        # account chips at the NEW variant from this tick on
-        cur_acc = _gather_v(st["var_acc"], v_cur)
-        thr = st["thr"] * _gather_v(st["var_smult"], v_cur)
-        chips = st["chips"] * _gather_v(st["var_cmult"], v_cur)
-        st_off = dict(
-            st,
-            lat_b1=st["lat_b1"] * _gather_v(st["var_lmult"], v_cur),
-            burst_cpr=(chips / thr) * st["burst_chip_s"] + st["inv_fee"],
-        )
-        # request: re-targeting the current variant cancels the in-flight
-        # swap; re-requesting the in-flight target leaves its clock
-        # alone; anything else (re)starts the slot
-        req = jnp.minimum(acts.get("variant", st["neg_i"]), st["var_n"] - 1)
-        cancel = (req >= 0) & (req == v_cur)
-        v_pend = jnp.where(cancel, -1, v_pend)
-        start = (req >= 0) & (req != v_cur) & (req != v_pend)
-        v_pend = jnp.where(start, req, v_pend)
-        v_ready = jnp.where(start, t + st["swap_lat"], state.var_ready)
-        v_last_move = acts.get("variant_last_move", state.var_last_move)
+        with jax.named_scope("scan.variants"):
+            done = (v_pend >= 0) & (state.var_ready <= t)
+            v_cur = jnp.where(done, v_pend, v_cur)
+            v_pend = jnp.where(done, -1, v_pend)
+            swaps = done.sum()
+            # POST-pop effective serving state (what _refresh_variant_state
+            # caches on the NumPy engine): serve, bill burst invocations and
+            # account chips at the NEW variant from this tick on
+            cur_acc = _gather_v(st["var_acc"], v_cur)
+            thr = st["thr"] * _gather_v(st["var_smult"], v_cur)
+            chips = st["chips"] * _gather_v(st["var_cmult"], v_cur)
+            st_off = dict(
+                st,
+                lat_b1=st["lat_b1"] * _gather_v(st["var_lmult"], v_cur),
+                burst_cpr=(chips / thr) * st["burst_chip_s"] + st["inv_fee"],
+            )
+            # request: re-targeting the current variant cancels the in-flight
+            # swap; re-requesting the in-flight target leaves its clock
+            # alone; anything else (re)starts the slot
+            req = jnp.minimum(acts.get("variant", st["neg_i"]), st["var_n"] - 1)
+            cancel = (req >= 0) & (req == v_cur)
+            v_pend = jnp.where(cancel, -1, v_pend)
+            start = (req >= 0) & (req != v_cur) & (req != v_pend)
+            v_pend = jnp.where(start, req, v_pend)
+            v_ready = jnp.where(start, t + st["swap_lat"], state.var_ready)
+            v_last_move = acts.get("variant_last_move", state.var_last_move)
     else:
         thr = st["thr"]
         chips = st["chips"]
         cur_acc = st["cur_acc"]
         st_off = st
 
-    # ---- provision (reserved, then aux in registration order).  Each
-    # tier's ring slot for this tick is t mod L (L static per tier) ----
-    res_active, res_pipe = _tier_set_target(
-        state.res_active,
-        _pipe_of(state, "res", lazy_rings),
-        acts["target"], t % state.res_ring.shape[1],
-    )
-    spot_active, spot_pipe, reclaimed = _spot_begin(
-        state.spot_active,
-        _pipe_of(state, "spot", lazy_rings),
-        xs["spot_u"], st["p_reclaim"],
-    )
-    spot_active, spot_pipe = _tier_set_target(
-        spot_active, spot_pipe, acts["spot"],
-        t % state.spot_ring.shape[1],
-    )
-    harv_active, harv_pipe, evicted = _harvest_begin(
-        state.harv_active,
-        _pipe_of(state, "harv", lazy_rings),
-        xs["h_ceil"],
-    )
-    harv_active, harv_pipe = _tier_set_target(
-        harv_active, harv_pipe, jnp.minimum(acts["harvest"], xs["h_ceil"]),
-        t % state.harv_ring.shape[1],
-    )
-    rem_active, rem_pipe = _tier_set_target(
-        state.rem_active,
-        _pipe_of(state, "rem", lazy_rings),
-        acts["remote"], t % state.rem_ring.shape[1],
-    )
-    preempt = reclaimed + evicted
+    with jax.named_scope("scan.provision"):
+        # ---- provision (reserved, then aux in registration order).  Each
+        # tier's ring slot for this tick is t mod L (L static per tier) ----
+        res_active, res_pipe = _tier_set_target(
+            state.res_active,
+            _pipe_of(state, "res", lazy_rings),
+            acts["target"], t % state.res_ring.shape[1],
+        )
+        spot_active, spot_pipe, reclaimed = _spot_begin(
+            state.spot_active,
+            _pipe_of(state, "spot", lazy_rings),
+            xs["spot_u"], st["p_reclaim"],
+        )
+        spot_active, spot_pipe = _tier_set_target(
+            spot_active, spot_pipe, acts["spot"],
+            t % state.spot_ring.shape[1],
+        )
+        harv_active, harv_pipe, evicted = _harvest_begin(
+            state.harv_active,
+            _pipe_of(state, "harv", lazy_rings),
+            xs["h_ceil"],
+        )
+        harv_active, harv_pipe = _tier_set_target(
+            harv_active, harv_pipe, jnp.minimum(acts["harvest"], xs["h_ceil"]),
+            t % state.harv_ring.shape[1],
+        )
+        rem_active, rem_pipe = _tier_set_target(
+            state.rem_active,
+            _pipe_of(state, "rem", lazy_rings),
+            acts["remote"], t % state.rem_ring.shape[1],
+        )
+        preempt = reclaimed + evicted
 
     # ---- serve: local capacity first (strict priority), then the
     # remote group against its egress-tightened lateness prefixes ------
-    cap_local = (res_active + spot_active + harv_active) * thr
-    qs_buf, served_s, late_s = _serve(qs_buf, cap_local, st["late_s"])
-    rem_cap = rem_active * thr
-    qs_buf, srs, lrs = _serve(qs_buf, rem_cap, st["rlate_s"])
-    qr_buf, served_r, late_r = _serve(
-        qr_buf, cap_local - served_s, st["late_r"]
-    )
-    qr_buf, srr, lrr = _serve(qr_buf, rem_cap - srs, st["rlate_r"])
-    served_s, late_s = served_s + srs, late_s + lrs
-    served_r, late_r = served_r + srr, late_r + lrr
-    served = served_s + served_r
-    cap_total = cap_local + rem_cap
-    util = jnp.where(
-        cap_total > 0,
-        served / jnp.where(cap_total > 0, cap_total, 1.0),
-        1.0,
-    )
-    viol_arch = late_s + late_r
-    viol_strict = late_s.sum()
+    with jax.named_scope("scan.serve"):
+        cap_local = (res_active + spot_active + harv_active) * thr
+        qs_buf, served_s, late_s = _serve(qs_buf, cap_local, st["late_s"])
+        rem_cap = rem_active * thr
+        qs_buf, srs, lrs = _serve(qs_buf, rem_cap, st["rlate_s"])
+        qr_buf, served_r, late_r = _serve(
+            qr_buf, cap_local - served_s, st["late_r"]
+        )
+        qr_buf, srr, lrr = _serve(qr_buf, rem_cap - srs, st["rlate_r"])
+        served_s, late_s = served_s + srs, late_s + lrs
+        served_r, late_r = served_r + srr, late_r + lrr
+        served = served_s + served_r
+        cap_total = cap_local + rem_cap
+        util = jnp.where(
+            cap_total > 0,
+            served / jnp.where(cap_total > 0, cap_total, 1.0),
+            1.0,
+        )
+        viol_arch = late_s + late_r
+        viol_strict = late_s.sum()
 
-    # ---- offload to burst (strict: any offload mode; relaxed: blind
-    # only), sequential so the relaxed batch sees a warmed pool --------
-    offload = acts["offload"]
-    qs_buf, counts_s, bviol_s, bcost_s, last_used = _offload(
-        qs_buf, offload >= 1, state.burst_last_used, t, st["slo_strict"],
-        st_off,
-    )
-    qr_buf, counts_r, bviol_r, bcost_r, last_used = _offload(
-        qr_buf, offload == 1, last_used, t, st["slo_relaxed"], st_off,
-    )
-    viol_arch = viol_arch + bviol_s + bviol_r
-    viol_strict = viol_strict + bviol_s.sum()
+        # ---- offload to burst (strict: any offload mode; relaxed: blind
+        # only), sequential so the relaxed batch sees a warmed pool --------
+        offload = acts["offload"]
+        qs_buf, counts_s, bviol_s, bcost_s, last_used = _offload(
+            qs_buf, offload >= 1, state.burst_last_used, t, st["slo_strict"],
+            st_off,
+        )
+        qr_buf, counts_r, bviol_r, bcost_r, last_used = _offload(
+            qr_buf, offload == 1, last_used, t, st["slo_relaxed"], st_off,
+        )
+        viol_arch = viol_arch + bviol_s + bviol_r
+        viol_strict = viol_strict + bviol_s.sum()
 
-    # ---- drop the bucket that aged past the abandon window (the
-    # oldest; subtracting its prefix zeroes column 0 exactly) ----------
-    dropped_s = qs_buf[:, 0]
-    qs_buf = jnp.maximum(qs_buf - dropped_s[:, None], 0.0)
-    dropped_r = qr_buf[:, 0]
-    qr_buf = jnp.maximum(qr_buf - dropped_r[:, None], 0.0)
-    dropped = dropped_s + dropped_r
-    viol_arch = viol_arch + dropped
-    viol_strict = viol_strict + dropped_s.sum()
+        # ---- drop the bucket that aged past the abandon window (the
+        # oldest; subtracting its prefix zeroes column 0 exactly) ----------
+        dropped_s = qs_buf[:, 0]
+        qs_buf = jnp.maximum(qs_buf - dropped_s[:, None], 0.0)
+        dropped_r = qr_buf[:, 0]
+        qr_buf = jnp.maximum(qr_buf - dropped_r[:, None], 0.0)
+        dropped = dropped_s + dropped_r
+        viol_arch = viol_arch + dropped
+        viol_strict = viol_strict + dropped_s.sum()
 
-    # ---- delivered accuracy ------------------------------------------
-    answered = served + counts_s + counts_r + dropped
-    acc_w = answered * cur_acc
-    acc_viol = answered * (cur_acc < st["acc_floor"] - 1e-12)
+    with jax.named_scope("scan.account"):
+        # ---- delivered accuracy ------------------------------------------
+        answered = served + counts_s + counts_r + dropped
+        acc_w = answered * cur_acc
+        acc_viol = answered * (cur_acc < st["acc_floor"] - 1e-12)
 
-    # ---- account ------------------------------------------------------
-    ch_res = res_active * chips
-    ch_spot = spot_active * chips
-    ch_harv = harv_active * chips
-    ch_rem = rem_active * chips
-    cost_arch = (
-        bcost_s + bcost_r
-        + ch_res * st["p_res"] + ch_spot * st["p_spot"]
-        + ch_harv * st["p_harv"] + ch_rem * st["p_rem"]
-    )
-    chip_all = ch_res + ch_spot + ch_harv + ch_rem
-    need = jnp.ceil(rate / thr) * chips
+        # ---- account ------------------------------------------------------
+        ch_res = res_active * chips
+        ch_spot = spot_active * chips
+        ch_harv = harv_active * chips
+        ch_rem = rem_active * chips
+        cost_arch = (
+            bcost_s + bcost_r
+            + ch_res * st["p_res"] + ch_spot * st["p_spot"]
+            + ch_harv * st["p_harv"] + ch_rem * st["p_rem"]
+        )
+        chip_all = ch_res + ch_spot + ch_harv + ch_rem
+        need = jnp.ceil(rate / thr) * chips
 
-    # summary key presence: a tier posts (even $0) only on live ticks
-    harv_live = (
-        harv_active.sum() + (harv_pipe.cum - harv_pipe.mat).sum()
-    ) > 0
-    rem_live = (
-        rem_active.sum() + (rem_pipe.cum - rem_pipe.mat).sum()
-    ) > 0
+        # summary key presence: a tier posts (even $0) only on live ticks
+        harv_live = (
+            harv_active.sum() + (harv_pipe.cum - harv_pipe.mat).sum()
+        ) > 0
+        rem_live = (
+            rem_active.sum() + (rem_pipe.cum - rem_pipe.mat).sum()
+        ) > 0
 
-    lazy_kw = {}
-    if lazy_rings:
-        for pre, pipe in (("res", res_pipe), ("spot", spot_pipe),
-                          ("harv", harv_pipe), ("rem", rem_pipe)):
-            lazy_kw[pre + "_ehist"] = pipe.ehist
-            lazy_kw[pre + "_sufmin"] = pipe.sufmin
-            lazy_kw[pre + "_bmin"] = pipe.bmin
-    var_ys = {}
-    if variants:
-        lazy_kw.update(var_cur=v_cur, var_pending=v_pend,
-                       var_ready=v_ready, var_last_move=v_last_move)
-        var_ys = {
-            # "swaps" is a flow (summed into the ledger); the rest are
-            # per-tick gauges matching the NumPy recorder's end_tick
-            # sampling points: active variant post-pop (swap.current),
-            # in-flight post-request (swap.in_flight), delivered
-            # accuracy at the serving variant (cur_acc)
-            "swaps": swaps,
-            "active_variant": v_cur,
-            "swap_in_flight": v_pend >= 0,
-            "acc_rate": cur_acc,
+        lazy_kw = {}
+        if lazy_rings:
+            for pre, pipe in (("res", res_pipe), ("spot", spot_pipe),
+                              ("harv", harv_pipe), ("rem", rem_pipe)):
+                lazy_kw[pre + "_ehist"] = pipe.ehist
+                lazy_kw[pre + "_sufmin"] = pipe.sufmin
+                lazy_kw[pre + "_bmin"] = pipe.bmin
+        var_ys = {}
+        if variants:
+            lazy_kw.update(var_cur=v_cur, var_pending=v_pend,
+                           var_ready=v_ready, var_last_move=v_last_move)
+            var_ys = {
+                # "swaps" is a flow (summed into the ledger); the rest are
+                # per-tick gauges matching the NumPy recorder's end_tick
+                # sampling points: active variant post-pop (swap.current),
+                # in-flight post-request (swap.in_flight), delivered
+                # accuracy at the serving variant (cur_acc)
+                "swaps": swaps,
+                "active_variant": v_cur,
+                "swap_in_flight": v_pend >= 0,
+                "acc_rate": cur_acc,
+            }
+        new_state = SimState(
+            qs_buf=qs_buf, qr_buf=qr_buf,
+            res_active=res_active,
+            res_ring=res_pipe.ring, res_cum=res_pipe.cum, res_mat=res_pipe.mat,
+            spot_active=spot_active,
+            spot_ring=spot_pipe.ring, spot_cum=spot_pipe.cum,
+            spot_mat=spot_pipe.mat,
+            harv_active=harv_active,
+            harv_ring=harv_pipe.ring, harv_cum=harv_pipe.cum,
+            harv_mat=harv_pipe.mat,
+            rem_active=rem_active,
+            rem_ring=rem_pipe.ring, rem_cum=rem_pipe.cum, rem_mat=rem_pipe.mat,
+            burst_last_used=last_used, last_util=util, last_viol=viol_arch,
+            prev_rate=rate,
+            ewma=ewma if ewma_in_carry else None,
+            **lazy_kw,
+        )
+        ys = {
+            "served": served,
+            "burst": counts_s + counts_r,
+            "dropped": dropped,
+            "viol": viol_arch,
+            "viol_strict": viol_strict,
+            "acc_w": acc_w,
+            "acc_viol": acc_viol,
+            "cost_arch": cost_arch,
+            "cost_res": ch_res.sum() * st["p_res"],
+            "cost_spot": ch_spot.sum() * st["p_spot"],
+            "cost_harv": ch_harv.sum() * st["p_harv"],
+            "cost_rem": ch_rem.sum() * st["p_rem"],
+            "cost_burst": bcost_s.sum() + bcost_r.sum(),
+            "preempt": preempt,
+            "chip": chip_all.sum(),
+            "need": need.sum(),
+            "over": jnp.maximum(chip_all - need, 0.0).sum(),
+            "harv_live": harv_live,
+            "rem_live": rem_live,
+            # fleet / queue gauges for the telemetry trajectory (exact zeros
+            # contribute nothing in "sum" mode; "stack" mode exposes the
+            # per-tick series run_scenario(record_trajectory=True) returns)
+            "n_res": res_active,
+            "n_spot": spot_active,
+            "n_harv": harv_active,
+            "n_rem": rem_active,
+            "queue_strict": qs_buf[:, -1],
+            "queue_relaxed": qr_buf[:, -1],
+            **var_ys,
+            **extras,
         }
-    new_state = SimState(
-        qs_buf=qs_buf, qr_buf=qr_buf,
-        res_active=res_active,
-        res_ring=res_pipe.ring, res_cum=res_pipe.cum, res_mat=res_pipe.mat,
-        spot_active=spot_active,
-        spot_ring=spot_pipe.ring, spot_cum=spot_pipe.cum,
-        spot_mat=spot_pipe.mat,
-        harv_active=harv_active,
-        harv_ring=harv_pipe.ring, harv_cum=harv_pipe.cum,
-        harv_mat=harv_pipe.mat,
-        rem_active=rem_active,
-        rem_ring=rem_pipe.ring, rem_cum=rem_pipe.cum, rem_mat=rem_pipe.mat,
-        burst_last_used=last_used, last_util=util, last_viol=viol_arch,
-        prev_rate=rate,
-        ewma=ewma if ewma_in_carry else None,
-        **lazy_kw,
-    )
-    ys = {
-        "served": served,
-        "burst": counts_s + counts_r,
-        "dropped": dropped,
-        "viol": viol_arch,
-        "viol_strict": viol_strict,
-        "acc_w": acc_w,
-        "acc_viol": acc_viol,
-        "cost_arch": cost_arch,
-        "cost_res": ch_res.sum() * st["p_res"],
-        "cost_spot": ch_spot.sum() * st["p_spot"],
-        "cost_harv": ch_harv.sum() * st["p_harv"],
-        "cost_rem": ch_rem.sum() * st["p_rem"],
-        "cost_burst": bcost_s.sum() + bcost_r.sum(),
-        "preempt": preempt,
-        "chip": chip_all.sum(),
-        "need": need.sum(),
-        "over": jnp.maximum(chip_all - need, 0.0).sum(),
-        "harv_live": harv_live,
-        "rem_live": rem_live,
-        # fleet / queue gauges for the telemetry trajectory (exact zeros
-        # contribute nothing in "sum" mode; "stack" mode exposes the
-        # per-tick series run_scenario(record_trajectory=True) returns)
-        "n_res": res_active,
-        "n_spot": spot_active,
-        "n_harv": harv_active,
-        "n_rem": rem_active,
-        "queue_strict": qs_buf[:, -1],
-        "queue_relaxed": qr_buf[:, -1],
-        **var_ys,
-        **extras,
-    }
     return new_state, ys
 
 
@@ -1031,6 +1039,7 @@ def _harvest_traj(seed: int, ticks: int) -> np.ndarray:
     return _HARV_CACHE[k]
 
 
+@telemetry.span("sim.prep.inputs")
 def build_sim_inputs(
     arrivals: np.ndarray,
     workload: List[ArchLoad],
@@ -1070,14 +1079,20 @@ def build_sim_inputs(
     is materialized.  Pass ``ewma_in_scan=False`` for the legacy
     host-precomputed input (``ewma`` optionally injects it); the runner
     flavor must match (:func:`_get_runner` ``flavor``).
+
+    Runs inside the program span ``sim.prep.inputs``; the template sim
+    and the monitor pass have spans of their own.
     """
     arrivals = np.asarray(arrivals, dtype=np.float64)
     assert arrivals.ndim == 2, "the JAX engine needs an [A, T] matrix"
     A, T = arrivals.shape
-    sim = _sim if _sim is not None else ServingSim(
-        arrivals, workload, pricing=pricing, prewarm=prewarm,
-        warm_start=warm_start, seed=seed, catalog=catalog,
-    )
+    sim = _sim
+    if sim is None:
+        with telemetry.span("sim.prep.template"):
+            sim = ServingSim(
+                arrivals, workload, pricing=pricing, prewarm=prewarm,
+                warm_start=warm_start, seed=seed, catalog=catalog,
+            )
     variants = sim._variants_live
 
     if ewma_in_scan is None:
@@ -1087,10 +1102,12 @@ def build_sim_inputs(
         if stats is not None:
             ewma, p2m = stats
         else:
-            ewma, _, p2m = pool_stats_trajectory(arrivals)
+            with telemetry.span("sim.prep.monitor"):
+                ewma, _, p2m = pool_stats_trajectory(arrivals)
     else:
         if not ewma_in_scan and ewma is None:
-            ewma = _ewma_trajectory(arrivals, LoadMonitor.ewma_alpha)
+            with telemetry.span("sim.prep.monitor"):
+                ewma = _ewma_trajectory(arrivals, LoadMonitor.ewma_alpha)
         # no policy on this path reads peak_to_median: a broadcastable
         # placeholder keeps it out of the grid's host->device traffic
         p2m = np.ones((T, 1), dtype=np.float64)
@@ -1338,11 +1355,12 @@ def make_runner(policy_apply, mode: str = "sum", *, unroll: int = 1,
                 state, acc = carry
                 state, ys = _tick(state, x, statics, policy_apply,
                                   ewma_in_carry, lazy_rings, variants)
-                acc = {
-                    k: (acc[k] | ys[k]) if k in _LIVE_KEYS
-                    else acc[k] + ys[k]
-                    for k in acc
-                }
+                with jax.named_scope("scan.account"):
+                    acc = {
+                        k: (acc[k] | ys[k]) if k in _LIVE_KEYS
+                        else acc[k] + ys[k]
+                        for k in acc
+                    }
                 return (state, acc), None
 
             (final, tot), _ = lax.scan(f, (state0, acc0), xs, unroll=unroll)
@@ -1414,7 +1432,7 @@ def _get_sharded_runner(policy: str, mesh, mode: str = "sum",
     from repro.distributed.sharding import AxisRules, logical_to_spec
 
     ndev = mesh.devices.size
-    key = (policy, mode, "sharded", ndev, flavor, variants)
+    key = _runner_key(policy, mode, True, flavor, variants, ndev)
     if key not in _RUNNERS:
         opts = _flavor_opts(policy, mode, flavor)
         opts["lazy_rings"] = False          # vmapped inside shard_map
@@ -1440,9 +1458,18 @@ def _get_sharded_runner(policy: str, mesh, mode: str = "sum",
     return _RUNNERS[key]
 
 
+def _runner_key(policy: str, mode: str, batched: bool, flavor: str,
+                variants: bool, sharded: int = 0) -> tuple:
+    """The :data:`_RUNNERS` key of a runner; ``sharded`` is the device
+    count of a ``shard_map`` grid runner, 0 for one device."""
+    if sharded:
+        return (policy, mode, "sharded", sharded, flavor, variants)
+    return (policy, mode, batched, flavor, variants)
+
+
 def _get_runner(policy: str, mode: str = "sum", batched: bool = False,
                 flavor: str = "opt", variants: bool = False):
-    key = (policy, mode, batched, flavor, variants)
+    key = _runner_key(policy, mode, batched, flavor, variants)
     if key not in _RUNNERS:
         opts = _flavor_opts(policy, mode, flavor)
         if batched:
@@ -1473,10 +1500,11 @@ def _get_runner(policy: str, mode: str = "sum", batched: bool = False,
 
 def runner_trace_count(policy: str, mode: str = "sum",
                        batched: bool = False, flavor: str = "opt",
-                       variants: bool = False) -> int:
+                       variants: bool = False, sharded: int = 0) -> int:
     """How many distinct shapes the cached runner has traced (the
-    recompile guard: repeated same-shape runs must report 1)."""
-    fn = _RUNNERS.get((policy, mode, batched, flavor, variants))
+    recompile guard: repeated same-shape runs must report 1);
+    ``sharded`` names the grid runner sharded over that many devices."""
+    fn = _RUNNERS.get(_runner_key(policy, mode, batched, flavor, variants, sharded))
     return 0 if fn is None else fn._cache_size()
 
 
@@ -1490,15 +1518,17 @@ _TRACE_WARNED: set = set()
 
 def note_runner_use(policy: str, mode: str = "sum",
                     batched: bool = False, flavor: str = "opt",
-                    variants: bool = False) -> int:
+                    variants: bool = False, sharded: int = 0) -> int:
     """Record a runner dispatch: export its trace count as a telemetry
-    counter and warn (once per key) if it retraced for an already-seen
-    ``(policy, mode, batched)`` key.  Returns the current trace count."""
-    key = (policy, mode, batched, flavor, variants)
-    n = runner_trace_count(policy, mode, batched, flavor, variants)
+    counter, labelled with every part of the runner's key, and warn
+    (once per key) if it retraced for an already-seen key.  Returns the
+    current trace count."""
+    key = _runner_key(policy, mode, batched, flavor, variants, sharded)
+    n = runner_trace_count(policy, mode, batched, flavor, variants, sharded)
     telemetry.set_global_counter(
         f'jax_runner_traces_total{{policy="{policy}",mode="{mode}",'
-        f'batched="{int(batched)}"}}', n)
+        f'batched="{int(batched)}",flavor="{flavor}",'
+        f'variants="{int(variants)}",sharded="{sharded}"}}', n)
     prev = _TRACE_SEEN.get(key)
     if prev is not None and n > prev and key not in _TRACE_WARNED:
         _TRACE_WARNED.add(key)
@@ -1594,9 +1624,22 @@ def _tree_index(tree, i):
     return jax.tree.map(lambda a: a[i], tree)
 
 
+def _tree_nbytes(tree) -> int:
+    """Bytes in a pytree's leaves: what a jit call copies to the device
+    for arguments given as host values."""
+    return sum(leaf.nbytes if hasattr(leaf, "nbytes") else np.asarray(leaf).nbytes
+               for leaf in jax.tree.leaves(tree))
+
+
 # ---------------------------------------------------------------------------
-# Public entry points.
+# Public entry points.  Each call is one telemetry.program_call; its
+# stages are program spans (docs/TELEMETRY.md): sim.prep.template,
+# sim.prep.monitor, sim.prep.inputs, sim.prep.stack, sim.dispatch (the
+# jit call, which returns once the arguments' copies to the device are
+# under way), sim.fetch (waits for those copies and the device, copies
+# the results back) and sim.assemble.
 # ---------------------------------------------------------------------------
+@telemetry.program_call()
 def run_scenario(
     arrivals: np.ndarray,
     workload: List[ArchLoad],
@@ -1632,28 +1675,32 @@ def run_scenario(
         prewarm=prewarm, warm_start=warm_start, needs_stats=pol.needs_stats,
         needs_key=pol.needs_key,
     )
+    telemetry.add_counter("sim_arch_ticks_total", xs["rate"].size)
     variants = "var_smult" in statics
     statics["policy"] = pol.default_params() if params is None else params
     mode = "stack" if record_trajectory else "sum"
+    runner = _get_runner(policy, mode=mode, variants=variants)
+    telemetry.add_counter("sim_h2d_bytes_total", _tree_nbytes((statics, state0, xs)))
     with jax.enable_x64(True):
-        out = _tree_to_host(
-            _get_runner(policy, mode=mode, variants=variants)(
-                statics, state0, xs
-            )
-        )
+        with telemetry.span("sim.dispatch"):
+            out = runner(statics, state0, xs)
+        with telemetry.span("sim.fetch"):
+            out = _tree_to_host(out)
     note_runner_use(policy, mode, variants=variants)
-    trajectory = None
-    if record_trajectory:
-        trajectory = out.pop("ys")
-        # reduce the stacked series host-side so _assemble sees the same
-        # shape the in-graph "sum" reduction produces
-        out["totals"] = {k: v.sum(axis=0) for k, v in trajectory.items()}
-    result = _assemble(out, np.asarray(arrivals, dtype=np.float64))
-    if record_trajectory:
-        result["trajectory"] = trajectory
+    with telemetry.span("sim.assemble"):
+        trajectory = None
+        if record_trajectory:
+            trajectory = out.pop("ys")
+            # reduce the stacked series host-side so _assemble sees the
+            # same shape the in-graph "sum" reduction produces
+            out["totals"] = {k: v.sum(axis=0) for k, v in trajectory.items()}
+        result = _assemble(out, np.asarray(arrivals, dtype=np.float64))
+        if record_trajectory:
+            result["trajectory"] = trajectory
     return result
 
 
+@telemetry.program_call()
 def run_grid(
     arrivals_batch: np.ndarray,              # [B, A, T]
     workload: List[ArchLoad],
@@ -1685,6 +1732,7 @@ def run_grid(
 
     arrivals_batch = np.asarray(arrivals_batch, dtype=np.float64)
     B, A, T = arrivals_batch.shape
+    telemetry.add_counter("sim_arch_ticks_total", B * A * T)
     pol = JAX_POLICIES[policy]
     seeds = list(seeds) if seeds is not None else [0] * B
     assert len(seeds) == B
@@ -1692,13 +1740,15 @@ def run_grid(
     # workload); per-cell monitor streams run as ONE batched recurrence
     # over the stacked [B*A, T] arrival matrix (rows are independent,
     # so the batched pass is bit-identical to B per-cell passes)
-    sim = ServingSim(
-        arrivals_batch[0], workload, pricing=pricing, prewarm=prewarm,
-        warm_start=warm_start, seed=seeds[0], catalog=catalog,
-    )
+    with telemetry.span("sim.prep.template"):
+        sim = ServingSim(
+            arrivals_batch[0], workload, pricing=pricing, prewarm=prewarm,
+            warm_start=warm_start, seed=seeds[0], catalog=catalog,
+        )
     variants = sim._variants_live
     if pol.needs_stats:
-        ew, _, p2 = pool_stats_trajectory(arrivals_batch.reshape(B * A, T))
+        with telemetry.span("sim.prep.monitor"):
+            ew, _, p2 = pool_stats_trajectory(arrivals_batch.reshape(B * A, T))
         stats = [
             (ew[:, i * A:(i + 1) * A], p2[:, i * A:(i + 1) * A])
             for i in range(B)
@@ -1716,33 +1766,41 @@ def run_grid(
         for i in range(B)
     ]
     statics = cells[0][0]
-    state0_b = _tree_stack([c[1] for c in cells])
-    xs_b = _tree_stack([c[2] for c in cells])
     if params_batch is None:
         params_batch = [pol.default_params() for _ in range(B)]
-    policy_b = _tree_stack(list(params_batch))
     mesh = device_mesh()
     use_shard = mesh is not None if sharded is None else sharded
     if use_shard:
         assert mesh is not None, "sharded run_grid needs more than one device"
-        pad = -B % mesh.devices.size
-        state0_b, xs_b, policy_b = (
-            jax.tree.map(
-                lambda a: np.concatenate([a, np.repeat(a[:1], pad, axis=0)]),
-                tree,
+    ndev = mesh.devices.size if use_shard else 0
+    with telemetry.span("sim.prep.stack"):
+        state0_b = _tree_stack([c[1] for c in cells])
+        xs_b = _tree_stack([c[2] for c in cells])
+        policy_b = _tree_stack(list(params_batch))
+        if use_shard:
+            pad = -B % ndev
+            state0_b, xs_b, policy_b = (
+                jax.tree.map(
+                    lambda a: np.concatenate([a, np.repeat(a[:1], pad, axis=0)]),
+                    tree,
+                )
+                for tree in (state0_b, xs_b, policy_b)
             )
-            for tree in (state0_b, xs_b, policy_b)
-        )
+    if use_shard:
         runner = _get_sharded_runner(policy, mesh, variants=variants)
     else:
         runner = _get_runner(policy, batched=True, variants=variants)
+    telemetry.add_counter("sim_h2d_bytes_total",
+                          _tree_nbytes((statics, policy_b, state0_b, xs_b)))
     with jax.enable_x64(True):
-        out = runner(statics, policy_b, state0_b, xs_b)
+        with telemetry.span("sim.dispatch"):
+            out = runner(statics, policy_b, state0_b, xs_b)
         devices = min(len(leaf.sharding.device_set) for leaf in jax.tree.leaves(out))
-        out = _tree_to_host(out)
-    if not use_shard:
-        note_runner_use(policy, batched=True, variants=variants)
-    return [
-        {**_assemble(_tree_index(out, i), arrivals_batch[i]), "devices": devices}
-        for i in range(B)
-    ]
+        with telemetry.span("sim.fetch"):
+            out = _tree_to_host(out)
+    note_runner_use(policy, batched=True, variants=variants, sharded=ndev)
+    with telemetry.span("sim.assemble"):
+        return [
+            {**_assemble(_tree_index(out, i), arrivals_batch[i]), "devices": devices}
+            for i in range(B)
+        ]

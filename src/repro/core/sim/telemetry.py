@@ -27,10 +27,14 @@ to the pre-telemetry engine.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
+import threading
+import time
+from collections import deque
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -44,12 +48,15 @@ __all__ = [
     "Telemetry",
     "TelemetryEvent",
     "TimeSeriesRecorder",
+    "add_counter",
     "detect_incidents",
     "events_from_jsonl",
     "global_counters",
     "incidents_table",
+    "program_call",
     "reconcile_events",
     "set_global_counter",
+    "span",
 ]
 
 
@@ -152,6 +159,71 @@ def set_global_counter(key: str, value: float) -> None:
 
 def global_counters() -> Dict[str, float]:
     return dict(GLOBAL_COUNTERS)
+
+
+# ---------------------------------------------------------------------------
+# Program spans of the JAX engine's entry points.  A span is a
+# ``jax.profiler.TraceAnnotation`` (so it lands in a profiler trace, on
+# the device's clock, when one is being taken) whose self time — its
+# duration minus its nested spans — is also kept in memory, per call:
+# :data:`CALLS` holds the most recent entry-point calls, oldest first,
+# each ``{<span name>: self seconds, <counter>: what the call added}``.
+# ---------------------------------------------------------------------------
+#: how many entry-point calls :data:`CALLS` keeps
+CALLS_KEPT = 1024
+CALLS: Deque[Dict[str, float]] = deque(maxlen=CALLS_KEPT)
+_COUNTER_LOCK = threading.Lock()
+
+
+class _OpenSpans(threading.local):
+    def __init__(self):
+        self.call: Optional[Dict[str, float]] = None
+        self.child_ns: List[int] = []     # per open span: its children's time
+
+
+_OPEN = _OpenSpans()
+
+
+@contextlib.contextmanager
+def program_call():
+    """One entry-point call (a decorator as well): the spans and
+    counters inside it are kept as one record of :data:`CALLS` when it
+    returns."""
+    outer = _OPEN.call
+    _OPEN.call = {}
+    try:
+        yield
+        CALLS.append(_OPEN.call)
+    finally:
+        _OPEN.call = outer
+
+
+@contextlib.contextmanager
+def span(name: str):
+    """A named program span (a decorator as well; the names are listed
+    in ``docs/TELEMETRY.md``)."""
+    from jax.profiler import TraceAnnotation
+
+    _OPEN.child_ns.append(0)
+    t0 = time.perf_counter_ns()
+    try:
+        with TraceAnnotation(name):
+            yield
+    finally:
+        dur = time.perf_counter_ns() - t0
+        self_ns = dur - _OPEN.child_ns.pop()
+        if _OPEN.child_ns:
+            _OPEN.child_ns[-1] += dur
+        if _OPEN.call is not None:
+            _OPEN.call[name] = _OPEN.call.get(name, 0.0) + self_ns / 1e9
+
+
+def add_counter(key: str, value: float) -> None:
+    """Add to a global counter, and to the open call's record."""
+    with _COUNTER_LOCK:
+        GLOBAL_COUNTERS[key] = GLOBAL_COUNTERS.get(key, 0.0) + float(value)
+    if _OPEN.call is not None:
+        _OPEN.call[key] = _OPEN.call.get(key, 0.0) + float(value)
 
 
 # ---------------------------------------------------------------------------
@@ -445,7 +517,7 @@ class Telemetry:
         if GLOBAL_COUNTERS:
             lines.append("# TYPE repro_counter gauge")
             for key in sorted(GLOBAL_COUNTERS):
-                lines.append(f"repro_{key} {GLOBAL_COUNTERS[key]:g}")
+                lines.append(f"repro_{key} {GLOBAL_COUNTERS[key]:.16g}")
         if result is not None:
             lines.append("# TYPE repro_sim_result gauge")
             for k, v in result.summary().items():

@@ -761,3 +761,106 @@ print("SHARDED_PARITY_OK")
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "SHARDED_PARITY_OK" in proc.stdout
+
+
+# ---------------------------------------------------------------------------
+# Named tick stages: compile-time metadata only.
+# ---------------------------------------------------------------------------
+TICK_STAGES = ("scan.observe", "scan.variants", "scan.policy",
+               "scan.provision", "scan.serve", "scan.account")
+
+
+def _compiled_runner_text(policy, batched, catalog):
+    """The optimized module of the runner ``run_grid`` (batched) or
+    ``run_scenario`` uses, at a tiny size."""
+    wl = _vworkload()
+    pol = je.JAX_POLICIES[policy]
+    cells = [
+        je.build_sim_inputs(
+            SCENARIO_ZOO["mmpp_bursts"].build(len(wl), duration_s=40, seed=i), wl,
+            catalog=catalog, seed=i, needs_stats=pol.needs_stats,
+            needs_key=pol.needs_key, lazy_rings=not batched)
+        for i in range(2)
+    ]
+    statics = cells[0][0]
+    runner = je._get_runner(policy, batched=batched, variants="var_smult" in statics)
+    if batched:
+        args = (statics, je._tree_stack([pol.default_params()] * 2),
+                je._tree_stack([c[1] for c in cells]),
+                je._tree_stack([c[2] for c in cells]))
+    else:
+        args = (dict(statics, policy=pol.default_params()), cells[0][1], cells[0][2])
+    with jax.enable_x64(True):
+        return runner.lower(*args).compile().as_text()
+
+
+def _path_components(text):
+    import re
+
+    return {c for p in re.findall(r'op_name="([^"]*)"', text) for c in p.split("/")}
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A persistent compilation cache keys programs without their scope
+    metadata, so a program cached without scopes would come back
+    without them: compile afresh."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.mark.parametrize("batched", [False, True])
+@pytest.mark.parametrize("with_catalog", [False, True])
+def test_tick_stages_are_named_in_the_runner(batched, with_catalog, vcatalog,
+                                             monkeypatch, no_compile_cache):
+    """Every stage scope is a whole path component of some op's
+    ``op_name`` in the compiled runner; ``scan.variants`` only where a
+    catalog puts the variant axis in the tick."""
+    monkeypatch.setattr(je, "_RUNNERS", {})
+    comps = _path_components(_compiled_runner_text(
+        "infaas_variant", batched, vcatalog if with_catalog else None))
+    assert set(TICK_STAGES) - {"scan.variants"} <= comps
+    assert ("scan.variants" in comps) == with_catalog
+
+
+def test_stages_and_spans_leave_results_bitwise(vcatalog, monkeypatch, no_compile_cache):
+    """With the scopes and the program spans (profiler off) the runners
+    compile to the same optimized module, metadata aside, and
+    ``run_grid`` / ``run_scenario`` return the same bits as without."""
+    import contextlib
+    import re
+
+    from repro.core.sim import telemetry
+
+    wl = _vworkload()
+    arrs = np.stack([SCENARIO_ZOO[n].build(len(wl), duration_s=120, seed=7 + i)
+                     for i, n in enumerate(("shared_berkeley", "flash_correlated"))])
+
+    def runs():
+        monkeypatch.setattr(je, "_RUNNERS", {})
+        out = je.run_grid(arrs, wl, "infaas_variant", seeds=[3, 4], catalog=vcatalog)
+        out.append(je.run_scenario(arrs[0], wl, "paragon", seed=3))
+        text = _compiled_runner_text("infaas_variant", True, vcatalog)
+        return out, text
+
+    def strip(text):
+        text = text[text.index("\n%"):]          # the file / frame tables
+        text = re.sub(r",? metadata=\{[^}]*\}", "", text)
+        return re.sub(r"%[\w.\-]+", "%", text)       # names (scope-derived)
+
+    scoped, scoped_text = runs()
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    monkeypatch.setattr(telemetry, "span", lambda name: contextlib.nullcontext())
+    plain, plain_text = runs()
+    assert "scan.policy" in scoped_text and "scan.policy" not in plain_text
+    assert strip(scoped_text) == strip(plain_text)
+    for a, b in zip(scoped, plain):
+        assert a["summary"] == b["summary"]
+        la, lb = jax.tree.leaves(a["raw"]), jax.tree.leaves(b["raw"])
+        assert len(la) == len(lb)
+        for x, y in zip(la, lb):
+            np.testing.assert_array_equal(x, y)

@@ -495,3 +495,153 @@ def test_ppo_training_log(tmp_path):
         assert np.isfinite([row["loss_mean"], row["entropy_mean"],
                             row["approx_kl"]]).all()
     assert rows == state.history            # the stream IS the history
+
+
+# ---------------------------------------------------------------------------
+# Program spans and counters of the JAX engine's entry points.
+# ---------------------------------------------------------------------------
+def test_runner_counter_labels_every_part_of_the_key():
+    """A catalog and a catalog-free runner of one policy are counted
+    under separate labels: neither overwrites the other's count."""
+    from repro.core.sim import jax_engine as je
+    from repro.core.sim.telemetry import global_counters
+
+    wl = [dataclasses.replace(w, min_accuracy=0.55)
+          for w in uniform_pool_workload(POOL[:3], strict_frac=0.25)]
+    arr = SCENARIO_ZOO["mmpp_bursts"].build(3, duration_s=60, mean_rps=60.0)
+    je.run_scenario(arr, wl, "infaas_variant")
+    je.run_scenario(arr, wl, "infaas_variant",
+                    catalog=VariantCatalog.for_workload(wl))
+    keys = [k for k in global_counters()
+            if k.startswith('jax_runner_traces_total{policy="infaas_variant",mode="sum",'
+                            'batched="0"')]
+    assert {k for k in keys if 'flavor="opt"' in k and 'sharded="0"' in k} == set(keys)
+    assert sorted('variants="1"' in k for k in keys) == [False, True]
+    for variants in (False, True):
+        n = je.runner_trace_count("infaas_variant", variants=variants)
+        assert n >= 1
+        key = next(k for k in keys if f'variants="{int(variants)}"' in k)
+        assert global_counters()[key] == n
+
+
+def test_sharded_runner_is_counted_subprocess():
+    """The sharded ``run_grid`` path notes its runner too, and
+    ``runner_trace_count`` finds it by its device count (two host
+    devices, so in a subprocess)."""
+    import os
+    import subprocess
+    import sys
+
+    from repro.core.sim import jax_engine as je
+
+    script = r"""
+import numpy as np, jax
+assert len(jax.devices()) == 2, jax.devices()
+from repro.core.sim import jax_engine as je
+from repro.core.sim.telemetry import global_counters
+from repro.core.sim.types import ArchLoad
+from repro.core.workloads import SCENARIO_ZOO
+wl = [ArchLoad("llama3-8b", 0.5, 0.25, name=f"m@{i}") for i in range(2)]
+arrs = np.stack([SCENARIO_ZOO["mmpp_bursts"].build(2, duration_s=40, seed=i)
+                 for i in range(2)])
+je.run_grid(arrs, wl, "reactive", seeds=[1, 2], sharded=True)
+assert je.runner_trace_count("reactive", batched=True, sharded=2) == 1
+assert je.runner_trace_count("reactive", batched=True) == 0
+key = ('jax_runner_traces_total{policy="reactive",mode="sum",batched="1",'
+       'flavor="opt",variants="0",sharded="2"}')
+assert global_counters()[key] == 1, global_counters()
+print("SHARDED_COUNTED_OK")
+"""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (
+        env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=2"
+    ).strip()
+    src = os.path.dirname(os.path.dirname(os.path.abspath(je.__file__)))
+    src = os.path.dirname(os.path.dirname(src))
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert "SHARDED_COUNTED_OK" in proc.stdout
+
+
+def test_program_spans_keep_self_time_per_call():
+    import time
+
+    from repro.core.sim import telemetry
+
+    @telemetry.program_call()
+    def call():
+        with telemetry.span("t.outer"):
+            time.sleep(0.02)
+            with telemetry.span("t.inner"):
+                time.sleep(0.03)
+            telemetry.add_counter("t_bytes_total", 5)
+        with telemetry.span("t.inner"):
+            time.sleep(0.01)
+        telemetry.add_counter("t_bytes_total", 2)
+
+    before = telemetry.GLOBAL_COUNTERS.get("t_bytes_total", 0.0)
+    call()
+    rec = telemetry.CALLS[-1]
+    assert set(rec) == {"t.outer", "t.inner", "t_bytes_total"}
+    assert rec["t_bytes_total"] == 7
+    assert telemetry.GLOBAL_COUNTERS["t_bytes_total"] == before + 7
+    # the outer span's self time leaves its nested span out
+    assert 0.02 <= rec["t.outer"] < 0.03 + 0.02
+    assert 0.04 <= rec["t.inner"] < 0.04 + 0.03
+    # outside a program call, spans and counters keep no record
+    n = len(telemetry.CALLS)
+    with telemetry.span("t.outer"):
+        telemetry.add_counter("t_bytes_total", 1)
+    assert len(telemetry.CALLS) == n
+
+
+def _tree_bytes(tree):
+    import jax
+
+    return sum(np.asarray(leaf).nbytes for leaf in jax.tree.leaves(tree))
+
+
+def test_h2d_counter_counts_the_runner_arguments():
+    """Each call adds the bytes of every argument it hands the runner."""
+    from repro.core.sim import jax_engine as je
+    from repro.core.sim import telemetry
+
+    wl = uniform_pool_workload(POOL[:3], strict_frac=0.25)
+    arrs = np.stack([SCENARIO_ZOO[n].build(3, duration_s=80, seed=i)
+                     for i, n in enumerate(("mmpp_bursts", "shared_berkeley"))])
+    pol = je.JAX_POLICIES["paragon"]
+
+    def counted(fn):
+        c0 = telemetry.GLOBAL_COUNTERS.get("sim_h2d_bytes_total", 0.0)
+        fn()
+        rec = telemetry.CALLS[-1]
+        assert telemetry.GLOBAL_COUNTERS["sim_h2d_bytes_total"] - c0 == rec["sim_h2d_bytes_total"]
+        return rec
+
+    rec = counted(lambda: je.run_scenario(arrs[0], wl, "paragon", seed=4))
+    statics, state0, xs = je.build_sim_inputs(arrs[0], wl, seed=4)
+    statics["policy"] = pol.default_params()
+    assert rec["sim_h2d_bytes_total"] == _tree_bytes((statics, state0, xs))
+    assert rec["sim_arch_ticks_total"] == arrs[0].size
+
+    rec = counted(lambda: je.run_grid(arrs, wl, "paragon", seeds=[4, 5]))
+    cells = [je.build_sim_inputs(a, wl, seed=s, lazy_rings=False)
+             for a, s in zip(arrs, (4, 5))]
+    policy_b = je._tree_stack([pol.default_params()] * 2)
+    want = _tree_bytes((cells[0][0], policy_b, je._tree_stack([c[1] for c in cells]),
+                        je._tree_stack([c[2] for c in cells])))
+    assert rec["sim_h2d_bytes_total"] == want
+    assert rec["sim_arch_ticks_total"] == arrs.size
+    # one record per call, each with every stage of the call
+    assert {"sim.prep.template", "sim.prep.monitor", "sim.prep.inputs",
+            "sim.prep.stack", "sim.dispatch", "sim.fetch", "sim.assemble"} <= set(rec)
+
+
+def test_prometheus_text_prints_counters_exactly():
+    from repro.core.sim import telemetry
+
+    telemetry.set_global_counter("t_exact_bytes_total", 1064818176)
+    text = Telemetry().prometheus_text()
+    assert "repro_t_exact_bytes_total 1064818176\n" in text

@@ -253,9 +253,10 @@ def collect_rollouts_jax_zoo(env: PoolServingEnv, params, key) -> dict:
     same ``vmap`` grid dispatch :func:`~repro.core.sim.jax_engine.run_grid`
     uses): per-cell arrival realizations, sim seeds and per-tick key
     streams are all distinct, the net's parameters are shared across
-    cells, and the per-cell monitor streams run as one batched
+    cells, and the per-cell monitor EWMAs run as one batched
     recurrence over the stacked ``[S*A, T]`` arrival matrix (rows are
-    independent, so this is bit-identical to S per-cell passes).
+    independent, so this is bit-identical to S per-cell passes; the
+    order statistics run in the runner, on the device).
 
     The returned buffers merge the cell axis into the arch axis —
     ``[T, S*A, ...]`` — so GAE and the PPO update treat the zoo batch
@@ -288,12 +289,12 @@ def collect_rollouts_jax_zoo(env: PoolServingEnv, params, key) -> dict:
         catalog=env.catalog,
     )
     variants = sim_tmpl._variants_live
-    ew, _, p2 = jax_engine.pool_stats_trajectory(arrs.reshape(S * A, T))
+    ew = jax_engine._ewma_trajectory(arrs.reshape(S * A, T))
     cells = [
         jax_engine.build_sim_inputs(
             arrs[i], env.workload, pricing=cfg.pricing, seed=seeds[i],
             needs_stats=True, needs_key=True, key=keys[i],
-            stats=(ew[:, i * A:(i + 1) * A], p2[:, i * A:(i + 1) * A]),
+            ewma=ew[:, i * A:(i + 1) * A],
             lazy_rings=False, _sim=sim_tmpl,
         )
         for i in range(S)

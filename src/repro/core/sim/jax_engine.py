@@ -57,15 +57,18 @@ fast without changing results:
   so the branchless form is identical (down to summary key presence,
   which per-tick liveness flags reconstruct).
 
-* **Host-precomputed inputs.**  Every stochastic or stream-derived
-  input is a pure function of ``(seed, tick)`` or of the arrival matrix
-  alone, so the monitor statistics
-  (:func:`~repro.core.load_monitor.pool_stats_trajectory`), the harvest
-  signal (:func:`~repro.core.sim.fleet.harvest_level_trajectory`) and
-  the spot reclaim uniforms
-  (:func:`~repro.core.sim.fleet.spot_reclaim_uniforms`) are
-  materialized host-side, bit-identical to the streams the NumPy tiers
-  consume, and fed to the scan as per-tick inputs.
+* **Precomputed inputs.**  Every stochastic or stream-derived input is
+  a pure function of ``(seed, tick)`` or of the arrival matrix alone.
+  The harvest signal
+  (:func:`~repro.core.sim.fleet.harvest_level_trajectory`), the spot
+  reclaim uniforms (:func:`~repro.core.sim.fleet.spot_reclaim_uniforms`)
+  and, for policies that read the order statistics, the monitor's EWMA
+  are materialized host-side, bit-identical to the streams the NumPy
+  engine consumes, and fed to the scan as per-tick inputs.  The
+  monitor's windowed peak-to-median ratio is computed inside the
+  runner, on the device, by a sorted-window pass over the rates before
+  the tick scan (:func:`_window_p2m`) — bit-identical to
+  :func:`~repro.core.load_monitor.pool_stats_trajectory`.
 
 Everything runs under ``jax.enable_x64(True)`` (float64, like
 the NumPy engine) without flipping the global flag — the float32 PPO
@@ -85,10 +88,7 @@ import numpy as np
 from jax import lax
 
 from repro.core.hardware import PRICING, FleetPricing
-from repro.core.load_monitor import (
-    LoadMonitor,
-    pool_stats_trajectory,
-)
+from repro.core.load_monitor import LoadMonitor
 from repro.core.rl.obs import (
     pool_features_arrays,
     procurement_targets_arrays,
@@ -664,6 +664,56 @@ def _pipe_of(state: SimState, pre: str, lazy: bool):
     return _Pipe(ring, cum, mat)
 
 
+def _window_p2m(rate):
+    """The monitor's windowed peak-to-median ratio, ``[T, A] -> [T, A]``,
+    bit-identical to the ``p2m`` of
+    :func:`~repro.core.load_monitor.pool_stats_trajectory`.
+
+    A ``lax.scan`` over ticks carries each arch's window sorted along
+    its major axis, ``[W, A]``, padded with ``+inf`` while it fills.
+    A tick deletes the leaving sample ``rate[t - W]`` (``+inf`` before
+    the window is full: one of the pads) and inserts the arriving one;
+    both positions are compare-and-count reductions over ``W`` and the
+    move is three selects against the window shifted by one row, so
+    nothing depends on the data but the values.  Peak and median are
+    rows of the first ``f = min(t + 1, W)``: ``f - 1``, and the mean of
+    ``(f - 1) // 2`` and ``f // 2`` as ``np.median`` takes it."""
+    W = LoadMonitor.window_s
+    A = rate.shape[1]
+    with jax.named_scope("sim.monitor"):
+        pad = jnp.full((1, A), jnp.inf, rate.dtype)
+        row = jnp.arange(W, dtype=jnp.int32)[:, None]
+
+        def step(win, x):
+            t, new = x
+            out = jnp.where(
+                t >= W,
+                lax.dynamic_index_in_dim(rate, jnp.maximum(t - W, 0),
+                                         keepdims=False),
+                jnp.inf,
+            )
+            # delete at the first copy of `out`; insert where `new` goes
+            # once `out` has left (it was counted iff out < new)
+            p = jnp.sum(win < out, axis=0, dtype=jnp.int32)
+            q = jnp.sum(win < new, axis=0, dtype=jnp.int32) - (out < new)
+            up = jnp.concatenate([win[1:], pad])       # row j holds j + 1
+            down = jnp.concatenate([pad, win[:-1]])    # row j holds j - 1
+            win = jnp.where(
+                row == q, new,
+                jnp.where((row >= p) & (row < q), up,
+                          jnp.where((row > q) & (row <= p), down, win)),
+            )
+            f = jnp.minimum(t + 1, W)
+            peak = lax.dynamic_index_in_dim(win, f - 1, keepdims=False)
+            med = 0.5 * (lax.dynamic_index_in_dim(win, (f - 1) // 2, keepdims=False)
+                         + lax.dynamic_index_in_dim(win, f // 2, keepdims=False))
+            return win, jnp.where(med > 0, peak / jnp.where(med > 0, med, 1.0), 1.0)
+
+        win0 = jnp.full((W, A), jnp.inf, rate.dtype)
+        _, p2m = lax.scan(step, win0, (jnp.arange(rate.shape[0]), rate))
+    return p2m
+
+
 def _gather_v(table, idx):
     """Row-wise gather from a padded ``[A, V]`` catalog table at ``[A]``
     indices (the scan form of ``np.take_along_axis(table, idx[:, None],
@@ -1012,9 +1062,11 @@ def _tick(state: SimState, xs: dict, st: dict, policy_apply,
 # ---------------------------------------------------------------------------
 # Host-side input builder.
 # ---------------------------------------------------------------------------
-def _ewma_trajectory(arrivals: np.ndarray, alpha: float) -> np.ndarray:
-    """The monitor's EWMA alone (for policies that never read the
-    order-statistic fields — skips the windowed median machinery)."""
+def _ewma_trajectory(arrivals: np.ndarray,
+                     alpha: float = LoadMonitor.ewma_alpha) -> np.ndarray:
+    """The monitor's EWMA alone, ``[A, T] -> [T, A]``, bit-identical to
+    :class:`~repro.core.load_monitor.PoolLoadMonitor`'s (the runner
+    computes the order statistics on the device)."""
     A, T = arrivals.shape
     out = np.empty((T, A), dtype=np.float64)
     e = arrivals[:, 0].astype(np.float64).copy()
@@ -1054,7 +1106,6 @@ def build_sim_inputs(
     key=None,
     ewma: Optional[np.ndarray] = None,
     ewma_in_scan: Optional[bool] = None,
-    stats: Optional[tuple] = None,
     lazy_rings: bool = True,
     _sim: Optional[ServingSim] = None,
 ):
@@ -1069,19 +1120,21 @@ def build_sim_inputs(
     :func:`run_grid` amortize that construction over cells sharing a
     workload (every sim-derived quantity is arrival- and
     seed-independent except the warm-start fleet, recomputed here), and
-    ``stats`` likewise injects precomputed ``(ewma, p2m)`` monitor
-    trajectories for ``needs_stats`` policies (the grid batches the
-    monitor across cells).
+    ``ewma`` likewise injects a precomputed ``[T, A]`` EWMA trajectory
+    (the grid batches the monitor across cells).
 
-    On the non-stats path the EWMA recurrence runs *inside* the scan by
-    default (``ewma_in_scan=None`` resolves to ``not needs_stats``):
-    ``state0.ewma`` seeds the carry and no ``[T, A]`` smoothing input
-    is materialized.  Pass ``ewma_in_scan=False`` for the legacy
-    host-precomputed input (``ewma`` optionally injects it); the runner
-    flavor must match (:func:`_get_runner` ``flavor``).
+    ``needs_stats`` policies get the host EWMA as ``xs["ewma"]`` and no
+    order statistics: the runner computes ``p2m`` from ``xs["rate"]``
+    on the device (:func:`_window_p2m`; the ``"legacy"`` flavor reads a
+    host ``xs["p2m"]`` its caller adds).  On the non-stats path the EWMA
+    recurrence runs *inside* the scan by default (``ewma_in_scan=None``
+    resolves to ``not needs_stats``): ``state0.ewma`` seeds the carry
+    and no ``[T, A]`` smoothing input is materialized.  Pass
+    ``ewma_in_scan=False`` for the legacy host-precomputed input; the
+    runner flavor must match (:func:`_get_runner` ``flavor``).
 
     Runs inside the program span ``sim.prep.inputs``; the template sim
-    and the monitor pass have spans of their own.
+    and the host EWMA pass have spans of their own.
     """
     arrivals = np.asarray(arrivals, dtype=np.float64)
     assert arrivals.ndim == 2, "the JAX engine needs an [A, T] matrix"
@@ -1097,20 +1150,10 @@ def build_sim_inputs(
 
     if ewma_in_scan is None:
         ewma_in_scan = not needs_stats
-    if needs_stats:
-        assert not ewma_in_scan, "stats policies read the monitor stream"
-        if stats is not None:
-            ewma, p2m = stats
-        else:
-            with telemetry.span("sim.prep.monitor"):
-                ewma, _, p2m = pool_stats_trajectory(arrivals)
-    else:
-        if not ewma_in_scan and ewma is None:
-            with telemetry.span("sim.prep.monitor"):
-                ewma = _ewma_trajectory(arrivals, LoadMonitor.ewma_alpha)
-        # no policy on this path reads peak_to_median: a broadcastable
-        # placeholder keeps it out of the grid's host->device traffic
-        p2m = np.ones((T, 1), dtype=np.float64)
+    assert not (needs_stats and ewma_in_scan), "stats policies read the monitor stream"
+    if not ewma_in_scan and ewma is None:
+        with telemetry.span("sim.prep.monitor"):
+            ewma = _ewma_trajectory(arrivals)
 
     cap = pricing.harvest_cap_per_arch
     lev = _harvest_traj(seed, T)
@@ -1234,7 +1277,6 @@ def build_sim_inputs(
     xs = {
         "t": np.arange(T, dtype=np.int64),
         "rate": np.ascontiguousarray(arrivals.T),
-        "p2m": p2m,
         "spot_u": spot_reclaim_uniforms(seed, T, A),
         "h_ceil": (lev * cap).astype(np.int64),
         "h_lev_obs": h_lev_obs,
@@ -1242,6 +1284,10 @@ def build_sim_inputs(
     }
     if not ewma_in_scan:
         xs["ewma"] = ewma
+    if not needs_stats:
+        # no policy on this path reads peak_to_median: a broadcastable
+        # placeholder keeps it out of the grid's host->device traffic
+        xs["p2m"] = np.ones((T, 1), dtype=np.float64)
     if needs_key:
         if key is None:
             key = jax.random.PRNGKey(seed)
@@ -1322,7 +1368,8 @@ SCAN_UNROLL = 1
 
 def make_runner(policy_apply, mode: str = "sum", *, unroll: int = 1,
                 ewma_in_carry: bool = False, accumulate: bool = False,
-                lazy_rings: bool = False, variants: bool = False):
+                lazy_rings: bool = False, variants: bool = False,
+                window_stats: bool = False):
     """Build ``run(statics, state0, xs) -> out`` around one policy.
 
     ``mode="sum"`` reduces the per-tick metrics (scenario evaluation);
@@ -1333,12 +1380,16 @@ def make_runner(policy_apply, mode: str = "sum", *, unroll: int = 1,
     re-reads hundreds of MB per run, the in-carry form touches only
     ``[A]`` accumulators.  ``unroll`` is passed through to ``lax.scan``
     (the chunked/unrolled option); ``ewma_in_carry`` moves the monitor
-    EWMA into the scan (see :func:`_tick`).  Not jitted or cached — see
-    :func:`_get_runner`.
+    EWMA into the scan (see :func:`_tick`); ``window_stats`` computes
+    the monitor's ``p2m`` input from ``xs["rate"]`` in the program
+    (:func:`_window_p2m`) instead of reading a host ``xs["p2m"]``.  Not
+    jitted or cached — see :func:`_get_runner`.
     """
     assert not (accumulate and mode != "sum")
 
     def run(statics, state0, xs):
+        if window_stats:
+            xs = {**xs, "p2m": _window_p2m(xs["rate"])}
         if accumulate:
             x0 = jax.tree.map(lambda a: a[0], xs)
             ys_shape = jax.eval_shape(
@@ -1402,17 +1453,22 @@ def _flavor_opts(policy: str, mode: str, flavor: str) -> dict:
 
     ``"opt"`` (default everywhere) carries the totals and — for
     policies that never read the order statistics — the EWMA in the
-    scan carry, and unrolls the scan; ``"legacy"`` reproduces the
+    scan carry, computes the order statistics on the device for those
+    that do, and unrolls the scan; ``"legacy"`` reproduces the
     pre-optimization construction (stacked per-tick outputs, host-fed
-    EWMA, unroll=1, no donation) and exists so the throughput benchmark
-    can A/B the two in one run on one machine."""
+    EWMA and — for stats policies, added by the caller from
+    :func:`~repro.core.load_monitor.pool_stats_trajectory` — host-fed
+    ``xs["p2m"]``, unroll=1, no donation) and exists so the throughput
+    benchmark can A/B the two in one run on one machine."""
     if flavor == "legacy":
         return dict(unroll=1, ewma_in_carry=False, accumulate=False,
                     lazy_rings=False)
     assert flavor == "opt", flavor
+    needs_stats = JAX_POLICIES[policy].needs_stats
     return dict(
         unroll=SCAN_UNROLL,
-        ewma_in_carry=not JAX_POLICIES[policy].needs_stats,
+        ewma_in_carry=not needs_stats,
+        window_stats=needs_stats,
         accumulate=(mode == "sum"),
         # under vmap the lazy rings' block-boundary cond decays to
         # select (both branches execute) — batched runners keep the
@@ -1676,6 +1732,8 @@ def run_scenario(
         needs_key=pol.needs_key,
     )
     telemetry.add_counter("sim_arch_ticks_total", xs["rate"].size)
+    telemetry.add_counter("sim_monitor_device_arch_ticks_total",
+                          xs["rate"].size if pol.needs_stats else 0)
     variants = "var_smult" in statics
     statics["policy"] = pol.default_params() if params is None else params
     mode = "stack" if record_trajectory else "sum"
@@ -1734,10 +1792,12 @@ def run_grid(
     B, A, T = arrivals_batch.shape
     telemetry.add_counter("sim_arch_ticks_total", B * A * T)
     pol = JAX_POLICIES[policy]
+    telemetry.add_counter("sim_monitor_device_arch_ticks_total",
+                          B * A * T if pol.needs_stats else 0)
     seeds = list(seeds) if seeds is not None else [0] * B
     assert len(seeds) == B
     # one template sim serves the whole grid (cells share the
-    # workload); per-cell monitor streams run as ONE batched recurrence
+    # workload); per-cell monitor EWMAs run as ONE batched recurrence
     # over the stacked [B*A, T] arrival matrix (rows are independent,
     # so the batched pass is bit-identical to B per-cell passes)
     with telemetry.span("sim.prep.template"):
@@ -1747,21 +1807,19 @@ def run_grid(
         )
     variants = sim._variants_live
     if pol.needs_stats:
+        # the order statistics run in the runner, on the device
         with telemetry.span("sim.prep.monitor"):
-            ew, _, p2 = pool_stats_trajectory(arrivals_batch.reshape(B * A, T))
-        stats = [
-            (ew[:, i * A:(i + 1) * A], p2[:, i * A:(i + 1) * A])
-            for i in range(B)
-        ]
+            ew = _ewma_trajectory(arrivals_batch.reshape(B * A, T))
+        ewmas = [ew[:, i * A:(i + 1) * A] for i in range(B)]
     else:
-        stats = [None] * B       # EWMA runs in the scan carry
+        ewmas = [None] * B       # EWMA runs in the scan carry
     cells = [
         build_sim_inputs(
             arrivals_batch[i], workload, pricing=pricing, seed=seeds[i],
             prewarm=prewarm, warm_start=warm_start,
             needs_stats=pol.needs_stats, needs_key=pol.needs_key,
             key=jax.random.PRNGKey(seeds[i]) if pol.needs_key else None,
-            stats=stats[i], lazy_rings=False, _sim=sim,
+            ewma=ewmas[i], lazy_rings=False, _sim=sim,
         )
         for i in range(B)
     ]

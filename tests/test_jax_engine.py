@@ -26,6 +26,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro.core.load_monitor import LoadMonitor, pool_stats_trajectory
 from repro.core.rl.obs import pool_features, pool_features_arrays
 from repro.core.schedulers import VECTOR_SCHEDULERS
 from repro.core.sim import ServingSim
@@ -321,7 +322,8 @@ def _scripted_parity(arr, wl, catalog, np_policy, jax_apply, seed=0):
         lazy_rings=False,
     )
     statics["policy"] = {}
-    run = jax.jit(je.make_runner(jax_apply, "sum", variants=True))
+    run = jax.jit(je.make_runner(jax_apply, "sum", variants=True,
+                                 window_stats=True))
     with jax.enable_x64(True):
         out = jax.tree.map(np.asarray, run(statics, state0, xs))
     res = je._assemble(out, np.asarray(arr, dtype=np.float64))
@@ -590,6 +592,98 @@ def test_smoke_grid_matches_run_scenario():
 
 
 # ---------------------------------------------------------------------------
+# The monitor's order statistics on the device.
+# ---------------------------------------------------------------------------
+_W = LoadMonitor.window_s
+
+
+def _host_p2m_construction(monkeypatch):
+    """Put back the construction from before the order statistics ran on
+    the device: ``p2m`` from the streaming monitor on the host, fed to a
+    runner that reads it."""
+    build, opts = je.build_sim_inputs, je._flavor_opts
+
+    def host_p2m(arrivals, *args, **kw):
+        statics, state0, xs = build(arrivals, *args, **kw)
+        if "p2m" not in xs:
+            xs["p2m"] = pool_stats_trajectory(arrivals)[2]
+        return statics, state0, xs
+
+    monkeypatch.setattr(je, "build_sim_inputs", host_p2m)
+    monkeypatch.setattr(je, "_flavor_opts",
+                        lambda *a: {**opts(*a), "window_stats": False})
+    monkeypatch.setattr(je, "_RUNNERS", {})
+
+
+def _assert_leaves_bitwise(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("policy", ["paragon", "infaas_variant", "rl_pool",
+                                    "portfolio", "reactive"])
+def test_device_order_stats_leave_entry_points_bitwise(policy, vcatalog, monkeypatch):
+    """``run_grid`` and ``run_scenario`` return the same bits as with
+    ``p2m`` made on the host; the xs of a stats policy carry no ``p2m``,
+    and a policy that reads no order statistics keeps its placeholder
+    and a runner without the device pass."""
+    wl = _vworkload()
+    A, T = len(wl), _W + 100
+    # load enough for fleets of several instances, whose size the
+    # bursty / flat headroom then moves
+    arrs = np.stack([SCENARIO_ZOO[n].build(A, duration_s=T, seed=11 + i, mean_rps=2000.0)
+                     for i, n in enumerate(("flash_correlated", "mmpp_bursts"))])
+    catalog = vcatalog if policy == "infaas_variant" else None
+    pol = je.JAX_POLICIES[policy]
+    _, _, xs = je.build_sim_inputs(arrs[0], wl, catalog=catalog,
+                                   needs_stats=pol.needs_stats)
+    assert ("p2m" in xs) == (not pol.needs_stats)
+    for mode in ("sum", "stack"):
+        assert je._flavor_opts(policy, mode, "opt")["window_stats"] == pol.needs_stats
+        assert "window_stats" not in je._flavor_opts(policy, mode, "legacy")
+    if not pol.needs_stats:
+        assert xs["p2m"].shape == (T, 1)
+
+    def runs():
+        monkeypatch.setattr(je, "_RUNNERS", {})
+        out = je.run_grid(arrs, wl, policy, seeds=[3, 4], catalog=catalog)
+        out.append(je.run_scenario(arrs[1], wl, policy, seed=4, catalog=catalog))
+        return out
+
+    device = runs()
+    _host_p2m_construction(monkeypatch)
+    host = runs()
+    for a, b in zip(device, host):
+        assert a["summary"] == b["summary"]
+        _assert_leaves_bitwise(a["raw"], b["raw"])
+
+
+@pytest.mark.parametrize("collector", ["collect_rollouts_jax", "collect_rollouts_jax_zoo"])
+def test_device_order_stats_leave_rollouts_bitwise(collector, monkeypatch):
+    """The PPO collectors (``rl_sample``) draw the same rollouts as with
+    ``p2m`` made on the host."""
+    from repro.core.rl import ppo
+    from repro.core.rl.env import EnvConfig, PoolServingEnv
+
+    A, T = 2, _W + 40
+    zoo = [SCENARIO_ZOO[n] for n in ("shared_berkeley", "flash_correlated")]
+    params = ppo.init_net(jax.random.key(0), ppo.PPOConfig())
+
+    def collect():
+        env = PoolServingEnv(_workload(A), EnvConfig(duration_s=T, mean_rps=40.0),
+                             scenarios=zoo, scenario_seed=0)
+        return getattr(ppo, collector)(env, params, jax.random.key(5))
+
+    device = collect()
+    _host_p2m_construction(monkeypatch)
+    host = collect()
+    assert set(device) == set(host)
+    _assert_leaves_bitwise(device, host)
+
+
+# ---------------------------------------------------------------------------
 # Shared building blocks.
 # ---------------------------------------------------------------------------
 def test_binomial_jnp_matches_numpy():
@@ -825,6 +919,9 @@ def test_tick_stages_are_named_in_the_runner(batched, with_catalog, vcatalog,
         "infaas_variant", batched, vcatalog if with_catalog else None))
     assert set(TICK_STAGES) - {"scan.variants"} <= comps
     assert ("scan.variants" in comps) == with_catalog
+    # the monitor's order statistics, before the tick scan (vmap wraps
+    # the scope's name in the grid)
+    assert comps & {"sim.monitor", "vmap(sim.monitor)"}
 
 
 def test_stages_and_spans_leave_results_bitwise(vcatalog, monkeypatch, no_compile_cache):

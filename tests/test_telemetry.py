@@ -622,6 +622,7 @@ def test_h2d_counter_counts_the_runner_arguments():
 
     rec = counted(lambda: je.run_scenario(arrs[0], wl, "paragon", seed=4))
     statics, state0, xs = je.build_sim_inputs(arrs[0], wl, seed=4)
+    assert "p2m" not in xs          # made from xs["rate"] on the device
     statics["policy"] = pol.default_params()
     assert rec["sim_h2d_bytes_total"] == _tree_bytes((statics, state0, xs))
     assert rec["sim_arch_ticks_total"] == arrs[0].size
@@ -637,6 +638,27 @@ def test_h2d_counter_counts_the_runner_arguments():
     # one record per call, each with every stage of the call
     assert {"sim.prep.template", "sim.prep.monitor", "sim.prep.inputs",
             "sim.prep.stack", "sim.dispatch", "sim.fetch", "sim.assemble"} <= set(rec)
+
+
+@pytest.mark.parametrize("policy", ["paragon", "portfolio"])
+def test_monitor_counter_says_where_the_order_statistics_ran(policy):
+    """Every arch-tick of a stats policy's call has its order statistics
+    computed in the runner; a policy that reads none computes none."""
+    from repro.core.sim import jax_engine as je
+    from repro.core.sim import telemetry
+
+    wl = uniform_pool_workload(POOL[:3], strict_frac=0.25)
+    arrs = np.stack([SCENARIO_ZOO[n].build(3, duration_s=60, seed=i)
+                     for i, n in enumerate(("mmpp_bursts", "flash_anti"))])
+    key = "sim_monitor_device_arch_ticks_total"
+    for call in (lambda: je.run_grid(arrs, wl, policy, seeds=[1, 2]),
+                 lambda: je.run_scenario(arrs[1], wl, policy, seed=2)):
+        c0 = telemetry.GLOBAL_COUNTERS.get(key, 0.0)
+        call()
+        rec = telemetry.CALLS[-1]
+        want = rec["sim_arch_ticks_total"] if je.JAX_POLICIES[policy].needs_stats else 0
+        assert rec[key] == want
+        assert telemetry.GLOBAL_COUNTERS[key] - c0 == want
 
 
 def test_prometheus_text_prints_counters_exactly():

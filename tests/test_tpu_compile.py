@@ -2,8 +2,9 @@
 
 The TPU compiler refuses what interpret mode accepts: block shapes off
 the (8, 128) tiling, 1-D or scalar VMEM scratch, too much fast memory.
-These tests compile the three Pallas kernels at real widths and the
-portfolio scan runner for one chip of a described ``v5e:2x2`` topology,
+These tests compile the three Pallas kernels at real widths, the
+portfolio scan runner and the paragon grid runner (with its device
+monitor pass) for one chip of a described ``v5e:2x2`` topology,
 so such a refusal shows up here rather than on the chip.  The topology
 is described inside a fixture only: describing it loads the TPU library,
 which one process at a time may hold.
@@ -90,4 +91,30 @@ def test_portfolio_scan_compiles_for_v5e(one_chip):
             (statics, state0, xs),
         )
         compiled = je._get_runner("portfolio").lower(*shapes).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_stats_grid_runner_compiles_for_v5e(one_chip):
+    """The float64 ``paragon`` grid runner, two cells at A=64, T=600:
+    its monitor pass (a scan over a sorted ``[W, A]`` window per cell)
+    and the tick scan must both go through the chip's compiler."""
+    from repro.core.sim import jax_engine as je
+    from repro.core.sim.types import replicate_pool
+    from repro.core.workloads import SCENARIO_ZOO
+
+    A, T, B = 64, 600, 2
+    wl = replicate_pool(["llama3-8b", "qwen1.5-0.5b", "rwkv6-1.6b"], A, 0.25)
+    arr = SCENARIO_ZOO["mmpp_bursts"].build(A, duration_s=T)
+    pol = je.JAX_POLICIES["paragon"]
+    statics, state0, xs = je.build_sim_inputs(arr, wl, needs_stats=True, lazy_rings=False)
+    assert "p2m" not in xs
+    cells = lambda tree: jax.tree.map(
+        lambda a: _spec(one_chip, (B,) + np.shape(a), jnp.asarray(a).dtype), tree)
+    with jax.enable_x64(True):
+        args = (
+            jax.tree.map(lambda a: _spec(one_chip, np.shape(a), jnp.asarray(a).dtype), statics),
+            cells(pol.default_params()), cells(state0), cells(xs),
+        )
+        compiled = je._get_runner("paragon", batched=True).lower(*args).compile()
+    assert "sim.monitor" in compiled.as_text()
     assert compiled.memory_analysis() is not None

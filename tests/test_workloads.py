@@ -7,7 +7,7 @@ import json
 import numpy as np
 import pytest
 
-from repro.core.load_monitor import LoadMonitor, PoolLoadMonitor
+from repro.core.load_monitor import LoadMonitor, PoolLoadMonitor, pool_stats_trajectory
 from repro.core.schedulers import SCHEDULERS, VECTOR_SCHEDULERS
 from repro.core.sim import ServingSim, shares, simulate, uniform_pool_workload
 from repro.core.traces import get_trace
@@ -346,3 +346,52 @@ def test_replay_drives_engine_and_env(workload, tmp_path):
                          scenarios=[sc])
     env.reset()
     np.testing.assert_array_equal(env.sim.arrivals, captured)
+
+
+# ---------------------------------------------------------------------------
+# The monitor's order statistics on the device (the JAX engine's runner).
+# ---------------------------------------------------------------------------
+W_MON = LoadMonitor.window_s
+
+
+def _monitor_streams(kind):
+    """``[A, T]`` arrival streams that stress the sorted window."""
+    rng = np.random.default_rng(17)
+    if kind == "zoo_rows":
+        return np.concatenate([
+            SCENARIO_ZOO[n].build(4, duration_s=W_MON + 80, seed=i)
+            for i, n in enumerate(("shared_berkeley", "flash_correlated",
+                                   "mmpp_bursts"))
+        ])
+    if kind == "heavy_ties":
+        return np.round(rng.gamma(0.5, 2.0, size=(8, W_MON + 150)))
+    if kind == "all_zero":
+        return np.zeros((3, W_MON + 20))
+    if kind == "zero_median":
+        # mostly zeros with spikes: the median sits at 0 while the peak does not
+        x = np.zeros((4, W_MON + 40))
+        x[:, ::7] = rng.integers(1, 9, size=x[:, ::7].shape)
+        return x
+    if kind.startswith("T="):
+        T = W_MON + int(kind[2:])
+        return np.round(rng.poisson(6.0, size=(5, T)) * rng.uniform(0.5, 2.0, size=(5, 1)))
+    assert kind == "A=1"
+    return rng.poisson(3.0, size=(1, W_MON + 60)).astype(np.float64)
+
+
+@pytest.mark.parametrize("kind", ["zoo_rows", "heavy_ties", "all_zero", "zero_median",
+                                  "T=-100", "T=0", "T=1", "A=1"])
+def test_window_p2m_matches_the_monitor_bitwise(kind):
+    """The runner's sorted-window pass gives the streaming monitor's
+    peak-to-median ratio to the bit: growing windows, the full window,
+    the first tick a sample leaves, ties, zero medians, one arch."""
+    import jax
+
+    from repro.core.sim import jax_engine as je
+
+    arr = _monitor_streams(kind)
+    _, _, want = pool_stats_trajectory(arr)
+    with jax.enable_x64(True):
+        got = np.asarray(jax.jit(je._window_p2m)(np.ascontiguousarray(arr.T)))
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(got.view(np.int64), want.view(np.int64))

@@ -32,9 +32,10 @@ def readings(cell: dict, seed: int) -> dict:
     reals = {k: traffic.realize(tr, cfg, seed, k) for k in sorted({k for k, _ in picks})}
     jobs = []
     for k, i in picks:
-        arr = reals[k]["arrivals"][i]
+        arr, sim_seed = reals[k]["arrivals"][i], reals[k]["sim_seeds"][i]
         for dt in (cfg["precision"], LOWER[cfg["precision"]]):
-            jobs.append({"arrivals": arr, "cfg": cfg, "dtype": dt})
+            jobs.append({"arrivals": arr, "cfg": cfg, "dtype": dt,
+                         "sim_seed": sim_seed})
     outs = check.references(jobs)
     per = [check.compare(outs[2 * n + 1], outs[2 * n]) for n in range(len(picks))]
     return check.worst(per)

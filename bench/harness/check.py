@@ -11,11 +11,16 @@ Three numbers, over every compared answer:
     the same over the ledger's totals (the cost of each tier and the
     provisioned, needed and idle chip-seconds);
 ``fleet_diff``
-    how many final per-stream fleet sizes, in-flight launches and active
-    variants differ, plus the gaps in the preemption and swap counts.
-    Integers, compared exactly.
+    how many final per-stream fleet sizes and in-flight launches of every
+    tier (reserved, spot, harvest, remote) and active variants differ,
+    plus the gaps in the preemption and swap counts.  Integers, compared
+    exactly.
 
-The reference runs in NumPy-only worker processes, so the process that
+A reference job is one answer's ``arrivals`` ``[A, T]``, the
+configuration (``cfg``), the float ``dtype`` and the ``sim_seed`` the
+program ran that answer with, which a reference of a seeded deployment
+(spot reclaims, the harvest signal) draws its streams from.  The
+reference runs in NumPy-only worker processes, so the process that
 holds the chip is never forked and the references of one run proceed
 side by side.
 """
@@ -45,7 +50,13 @@ def _rel(got, want) -> float:
 
 
 def compare(got: dict, want: dict) -> Dict[str, float]:
-    """The three numbers for one answer."""
+    """The three numbers for one answer.  Both sides must state the same
+    flows and the same fleet keys: a key that only one side states
+    raises instead of passing unseen."""
+    for part in ("flows", "fleet"):
+        if set(got[part]) != set(want[part]):
+            raise KeyError(f"{part} keys differ: program {sorted(got[part])}, "
+                           f"reference {sorted(want[part])}")
     flow = max(_rel(got["flows"][k], want["flows"][k]) for k in want["flows"])
     cost = max(_rel(got["totals"][k], want["totals"][k]) for k in COST_KEYS)
     fleet = sum(int(np.sum(np.asarray(got["fleet"][k]) != np.asarray(want["fleet"][k])))

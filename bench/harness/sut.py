@@ -53,7 +53,11 @@ class Program:
 
 def extract(res: dict) -> dict:
     """The per-stream flows, ledger totals and final fleet of one
-    answer, as host arrays (the names of the reference's output)."""
+    answer, as host arrays (the names of the reference's output).  The
+    fleet is every tier's: the reserved tier's ``active`` and
+    ``pending`` (launches in flight), the active ``variant``, and
+    ``<tier>_active`` / ``<tier>_pending`` for spot, harvest and
+    remote."""
     pa, raw = res["per_arch"], res["raw"]
     tot, final = raw["totals"], raw["final"]
     A = len(pa["arrived"])
@@ -74,8 +78,23 @@ def extract(res: dict) -> dict:
         "preemptions": int(tot["preempt"]),
         "variant_swaps": int(tot["swaps"]) if "swaps" in tot else 0,
     }
-    var = final.var_cur if final.var_cur is not None else np.zeros(A)
-    fleet = {"active": np.asarray(final.res_active, dtype=np.int64),
-             "pending": np.asarray(final.res_cum - final.res_mat, dtype=np.int64),
-             "variant": np.asarray(var, dtype=np.int64)}
+    fleet = {"active": _ints(final.res_active, A),
+             "pending": _ints(_in_flight(final, "res"), A),
+             "variant": _ints(final.var_cur, A)}
+    for name, tier in (("spot", "spot"), ("harvest", "harv"), ("remote", "rem")):
+        fleet[name + "_active"] = _ints(getattr(final, tier + "_active"), A)
+        fleet[name + "_pending"] = _ints(_in_flight(final, tier), A)
     return {"flows": flows, "totals": totals, "fleet": fleet}
+
+
+def _in_flight(final, tier: str):
+    """A tier's launches in flight, cumulative granted less matured
+    (``None`` where the state does not hold the tier)."""
+    cum, mat = getattr(final, tier + "_cum"), getattr(final, tier + "_mat")
+    return None if cum is None or mat is None else cum - mat
+
+
+def _ints(x, A: int) -> np.ndarray:
+    """A per-stream count as ``int64``; a state the run does not hold
+    (``None``) reads as zeros."""
+    return np.zeros(A, dtype=np.int64) if x is None else np.asarray(x, dtype=np.int64)

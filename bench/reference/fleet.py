@@ -376,11 +376,15 @@ def simulate(arrivals: np.ndarray, cfg: dict, dtype=np.float64) -> dict:
                    "cost_remote": f(0), "preemptions": 0,
                    "variant_swaps": swaps},
         "fleet": {"active": active, "pending": pipe.sum(axis=1),
-                  "variant": cur},
+                  "variant": cur, **{f"{tier}_{k}": np.zeros(A, dtype=np.int64)
+                                     for tier in ("spot", "harvest", "remote")
+                                     for k in ("active", "pending")}},
     }
 
 
 def run_cell(job: dict) -> dict:
-    """Worker entry: ``job`` holds ``arrivals``, ``cfg`` and ``dtype``
-    (a NumPy dtype name)."""
+    """Worker entry: ``job`` holds ``arrivals``, ``cfg``, ``dtype`` (a
+    NumPy dtype name) and ``sim_seed``.  The seed is unused: ``paragon``
+    and ``infaas_variant`` over reserved slices and burst draw no random
+    number, and hold no spot, harvest or remote capacity."""
     return simulate(job["arrivals"], job["cfg"], np.dtype(job["dtype"]))

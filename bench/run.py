@@ -9,7 +9,9 @@ The window: closed-loop calls of the program's entry point, each on the
 next realization, until ``--seconds`` have passed; the window holds
 whole calls.  Nothing may compile inside it.  Then the check: a sample
 of the window's answers, drawn from the seed, is recomputed by the plain
-reference and compared.
+reference and compared.  Each answer's reference job is its
+``arrivals``, the configuration (``cfg``), the configuration's float
+``dtype`` and the ``sim_seed`` the program ran that answer with.
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` runs
 the window under the profiler and reports the per-layer metrics read
@@ -187,7 +189,8 @@ def run(args, *, cell=None, require_accelerator: bool = True) -> dict:
     # the check: a sample of the window's answers against the reference
     picks = check.sample(len(answers), n_cells, int(tr["sample"]), args.seed)
     jobs = [{"arrivals": answers[j][i][0]["arrivals"][i], "cfg": cfg,
-             "dtype": cfg["precision"]} for j, i in picks]
+             "dtype": cfg["precision"], "sim_seed": answers[j][i][0]["sim_seeds"][i]}
+            for j, i in picks]
     t0 = time.perf_counter()
     wants = check.references(jobs)
     per_answer = [check.compare(answers[j][i][2], w) for (j, i), w in zip(picks, wants)]

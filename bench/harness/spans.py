@@ -13,9 +13,20 @@ and the tick as named scopes (``scan.*``).  Two readers:
   metrics in ``bench/metrics``;
 * from a profiler trace, where the spans lie on the device's clock:
   :func:`read_program_trace` gives each call's span self times, each
-  tick stage's device self time, and the idle time under each innermost
-  span (:func:`idle_by_span`).  ``bench/split.py`` prints that split for
-  a cell.
+  scope's device self time, and the idle time under each innermost
+  span (:func:`idle_by_span`).  A ``--trace 1`` run hands the metric
+  readers each scope's device time per arch-tick
+  (:func:`scope_ns_per_arch_tick`); ``bench/split.py`` prints the
+  whole split for a cell.
+
+A trace's op event names only its HLO instruction, so an op's scope
+comes from the compiled module of the runner that ran it: the runners'
+arguments are kept while they run (:class:`RunnerArgs`), and their
+compiled text read after (:func:`compiled_op_paths`), from a program
+compiled from this code (:func:`cache_keys_with_scopes`).  The scopes a
+trace is split by are :data:`STAGES` and every scope a metric reader
+names (:func:`split_scopes`), so a new scope is read by adding one
+metric file.
 
 The functions below the readers work on plain tuples, so they are
 tested on synthetic events as well as on a recorded trace.
@@ -24,7 +35,7 @@ from __future__ import annotations
 
 import bisect
 import re
-from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 SPAN_PREFIX = "sim."
 STAGES = ("scan.observe", "scan.variants", "scan.policy", "scan.provision",
@@ -144,13 +155,40 @@ def _in_call(calls, s, name):
     return None if i is None else (i, name)
 
 
-def stage_of(op_path: str) -> str:
-    """The tick stage of an op from its HLO ``op_name`` path: the last
-    path component that is a stage's whole name, else :data:`UNSCOPED`."""
+def split_scopes(named: Iterable[str]) -> Tuple[str, ...]:
+    """The scopes a trace is split by: :data:`STAGES`, then each of
+    ``named`` that is not one of them."""
+    return STAGES + tuple(s for s in dict.fromkeys(named) if s not in STAGES)
+
+
+#: a path component a transformation renamed: ``vmap(sim.monitor)`` is
+#: the scope ``sim.monitor`` entered first inside a vmapped function
+_TRANSFORMED = re.compile(r"^(?:[\w.\-]+\()+([^()]*)\)+$")
+
+
+def stage_of(op_path: str, stages: Sequence[str] = STAGES) -> str:
+    """The scope of an op from its HLO ``op_name`` path: the last path
+    component that is one of ``stages`` by its whole name, or wraps one
+    in a transformation's name, else :data:`UNSCOPED`."""
     for comp in reversed(op_path.split("/")):
-        if comp in STAGES:
-            return comp
+        m = _TRANSFORMED.match(comp)
+        name = m.group(1) if m else comp
+        if name in stages:
+            return name
     return UNSCOPED
+
+
+def scope_ns_per_arch_tick(stage_ns: Sequence[Dict[str, int]],
+                           arch_ticks_per_call: int) -> Dict[str, float]:
+    """Each scope's device self time summed over the calls
+    (:func:`read_program_trace`'s ``stage_ns``) over the arch-ticks they
+    simulated; a scope that ran on no op has no entry."""
+    total: Dict[str, int] = {}
+    for st in stage_ns:
+        for k, v in st.items():
+            total[k] = total.get(k, 0) + v
+    ticks = len(stage_ns) * arch_ticks_per_call
+    return {k: v / ticks for k, v in total.items()} if ticks else {}
 
 
 def idle_by_span(idle: Sequence[Tuple[int, int]],
@@ -184,19 +222,79 @@ def hlo_op_paths(hlo_text: str) -> Dict[str, str]:
 
 
 # ---------------------------------------------------------------------------
+# The compiled modules of the runners a run used.
+# ---------------------------------------------------------------------------
+def cache_keys_with_scopes() -> Callable[[], None]:
+    """Key JAX's persistent compilation cache by the program's metadata
+    as well, until the returned function is called.  The key leaves the
+    metadata out by default, so a program cached from other code could
+    come back with other scopes, or none; keyed by it, a cached program
+    carries this code's scopes, and only the first run compiles."""
+    import jax
+
+    was = jax.config.jax_compilation_cache_include_metadata_in_key
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    return lambda: jax.config.update("jax_compilation_cache_include_metadata_in_key", was)
+
+
+class RunnerArgs:
+    """While open, every runner the fleet engine hands out from its
+    registry (``jax_engine._RUNNERS``) keeps the arguments of its last
+    call in :attr:`args`, ``{registry key: (runner, args)}``.
+    :meth:`close` puts the registry back: the same dict, holding every
+    runner made while open, unwrapped."""
+
+    def __init__(self, engine):
+        self.engine, self.registry = engine, engine._RUNNERS
+        self.args: Dict[Any, tuple] = {}
+        keep = self.args
+
+        class Keeping(dict):
+            def __getitem__(self, key):
+                fn = dict.__getitem__(self, key)
+
+                def run(*a):
+                    keep[key] = (fn, a)
+                    return fn(*a)
+                return run
+
+        engine._RUNNERS = Keeping(self.registry)
+
+    def close(self) -> None:
+        self.registry.update(self.engine._RUNNERS)
+        self.engine._RUNNERS = self.registry
+
+
+def compiled_op_paths(args: Dict[Any, tuple]) -> Dict[str, str]:
+    """Instruction-to-``op_name`` map of every runner in ``args``
+    (:attr:`RunnerArgs.args`), from its compiled module.  The runners
+    ran these arguments already, so their executables are in memory and
+    nothing compiles."""
+    import jax
+
+    out: Dict[str, str] = {}
+    with jax.enable_x64(True):
+        for fn, a in args.values():
+            out.update(hlo_op_paths(fn.lower(*a).compile().as_text()))
+    return out
+
+
+# ---------------------------------------------------------------------------
 # From a profiler trace.
 # ---------------------------------------------------------------------------
 def read_program_trace(path: str, lines, calls: Sequence[Tuple[int, int]],
-                       op_paths: Dict[str, str], top: int = 15) -> dict:
+                       op_paths: Dict[str, str], top: int = 15,
+                       stages: Sequence[str] = STAGES) -> dict:
     """From one trace file and the harness's call spans (``(start,
     end)``, sorted): ``spans``, the program's spans as ``(start, end,
-    name)``; ``stage_ns``, per call the device self time of each tick
-    stage; ``top_ops``, the ``top`` device ops by self time over the
-    calls, as ``(op, stage, op_name path, ns)``.  An op event names only
-    its HLO instruction (on the TPU and on the CPU alike), so its stage
-    comes from ``op_paths``, the compiled module's instruction-to-path
-    map (:func:`hlo_op_paths`).  ``lines`` gives the roles of a trace
-    line, as in :mod:`harness.trace`."""
+    name)``; ``stage_ns``, per call the device self time of each of
+    ``stages`` and of :data:`UNSCOPED`; ``top_ops``, the ``top`` device
+    ops by self time over the calls, as ``(op, stage, op_name path,
+    ns)``.  An op event names only its HLO instruction (on the TPU and
+    on the CPU alike), so its stage comes from ``op_paths``, the
+    compiled module's instruction-to-path map (:func:`hlo_op_paths`).
+    ``lines`` gives the roles of a trace line, as in
+    :mod:`harness.trace`."""
     import jax
 
     from harness.trace import op_name
@@ -228,10 +326,11 @@ def read_program_trace(path: str, lines, calls: Sequence[Tuple[int, int]],
     per_call: List[Dict[str, int]] = [dict() for _ in calls]
     per_op: Dict[str, int] = {}
     for (i, op), ns in op_ns.items():
-        st = stage_of(op_paths.get(op, ""))
+        st = stage_of(op_paths.get(op, ""), stages)
         per_call[i][st] = per_call[i].get(st, 0) + ns
         per_op[op] = per_op.get(op, 0) + ns
     ranked = sorted(per_op.items(), key=lambda kv: -kv[1])[:top]
     return {"spans": sorted(spans), "stage_ns": per_call,
-            "top_ops": [(op, stage_of(op_paths.get(op, "")), op_paths.get(op, ""), ns)
+            "top_ops": [(op, stage_of(op_paths.get(op, ""), stages),
+                         op_paths.get(op, ""), ns)
                         for op, ns in ranked]}

@@ -15,10 +15,14 @@ reference and compared.  Each answer's reference job is its
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` runs
 the window under the profiler and reports the per-layer metrics read
-from the trace.  The last line of standard output is one JSON object;
-the compared numbers and their limits are the last lines of standard
-error.  Exits non-zero, printing no result, where JAX finds no
-accelerator or fewer chips than the cell asks for.
+from the trace.  The traced run keys the persistent cache by the
+program's metadata too, so that the program it runs carries this code's
+scopes, and keeps the arguments each runner got in the warm call; after
+the window it reads the runners' compiled text, which maps each device
+op of the trace to its program scope.  The last line of standard
+output is one JSON object; the compared numbers and their limits are
+the last lines of standard error.  Exits non-zero, printing no result,
+where JAX finds no accelerator or fewer chips than the cell asks for.
 """
 from __future__ import annotations
 
@@ -92,7 +96,7 @@ def run(args, *, cell=None, require_accelerator: bool = True) -> dict:
     """One run of one cell; returns the result object.  ``cell`` (the
     loaded files, as :func:`harness.spec.cell` gives them) lets a test
     run a resized copy."""
-    from harness import check, spec, sut, traffic as traffic_mod
+    from harness import check, spans, spec, sut, traffic as traffic_mod
 
     bench = spec.benchmark()
     cell = cell or spec.cell(args.workload, bench)
@@ -107,13 +111,22 @@ def run(args, *, cell=None, require_accelerator: bool = True) -> dict:
     program = sut.Program(cfg, tr["entry"])
     reals = [traffic_mod.realize(tr, cfg, args.seed, k)
              for k in range(int(tr["realizations"]))]
+    if args.trace:
+        # the program carries this code's scopes; the window calls the
+        # runners unwrapped
+        restore_keys = spans.cache_keys_with_scopes()
+        runners = spans.RunnerArgs(program.je)
     program.call(reals[0])                       # warm: compile or load
+    if args.trace:
+        runners.close()
+        restore_keys()
     setup_s = time.perf_counter() - T_START
     compiles0 = compiles_so_far()
     log(f"[bench] set-up {setup_s:.3f} s")
 
     if args.trace:
-        trace_dir = os.path.join(ROOT, ".bench_trace", args.workload)
+        # one directory per process: test processes trace side by side
+        trace_dir = os.path.join(ROOT, ".bench_trace", f"{args.workload}.{os.getpid()}")
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.enable_hlo_proto = False
@@ -155,13 +168,18 @@ def run(args, *, cell=None, require_accelerator: bool = True) -> dict:
     if args.trace:
         from harness import trace as trace_mod
 
-        spans, busy, op_ns = trace_mod.read_trace(
-            trace_mod.trace_file(trace_dir),
-            trace_mod.tpu_lines if dev["platform"] == "tpu" else trace_mod.cpu_lines)
-        red = trace_mod.reduce(spans, busy, op_ns)
+        op_paths = spans.compiled_op_paths(runners.args)
+        path = trace_mod.trace_file(trace_dir)
+        lines = trace_mod.tpu_lines if dev["platform"] == "tpu" else trace_mod.cpu_lines
+        calls, busy, op_ns = trace_mod.read_trace(path, lines)
+        red = trace_mod.reduce(calls, busy, op_ns)
+        scopes = spans.split_scopes(spec.metric_scopes(bench))
+        prog = spans.read_program_trace(path, lines, calls, op_paths, stages=scopes)
         shutil.rmtree(trace_dir, ignore_errors=True)
         ctx = {"trace": red, "arch_ticks_per_call": n_cells * A * T,
-               "memory_peak_bytes": peak}
+               "memory_peak_bytes": peak,
+               "scope_ns_per_arch_tick": spans.scope_ns_per_arch_tick(
+                   prog["stage_ns"], n_cells * A * T)}
         metrics = {}
         for name, (read, unit) in spec.metric_readers(bench).items():
             v = read(ctx)

@@ -7,13 +7,14 @@ file's ``trace_calls`` calls untraced and as many under the profiler,
 each inside the harness's call span.  Prints one JSON object: per call,
 the host clock's call time traced and untraced (the profiler's cost);
 from the trace, the self time of each program span (``sim.*``), each
-tick stage's device self time per arch-tick (``scan.*``, the rest
-``unscoped``), the idle time under each innermost program span, and the
-harness's own readings (``host_prep_ms``, ``scan_ns_per_arch_tick``) of
-the same calls to compare the sums with; the device ops that took most
-self time (ns per arch-tick of a call), with their stage and ``op_name``
-path; and the program's in-memory records of the traced calls.  Exits
-non-zero where JAX finds no accelerator.
+scope's device self time per arch-tick (the tick stages ``scan.*`` and
+every scope a metric reader names, the rest ``unscoped``), the idle
+time under each innermost program span, and the harness's own readings
+(``host_prep_ms``, ``scan_ns_per_arch_tick``) of the same calls to
+compare the sums with; the device ops that took most self time (ns per
+arch-tick of a call), with their stage and ``op_name`` path; and the
+program's in-memory records of the traced calls.  Exits non-zero where
+JAX finds no accelerator.
 """
 from __future__ import annotations
 
@@ -31,57 +32,24 @@ sys.path[:0] = [BENCH_DIR, os.path.join(ROOT, "src")]
 import run as bench_run  # noqa: E402
 
 
-class _Keep:
-    """A runner that keeps the arguments of its last call."""
-
-    def __init__(self, fn, key, captured):
-        self.fn, self.key, self.captured = fn, key, captured
-
-    def __call__(self, *args):
-        self.captured[self.key] = (self.fn, args)
-        return self.fn(*args)
-
-    def __getattr__(self, name):
-        return getattr(self.fn, name)
-
-
-def _compiled_paths(je, captured) -> dict:
-    """Instruction-to-``op_name`` map of every runner the calls used,
-    from their compiled modules."""
-    import jax
-
-    from harness import spans
-
-    out = {}
-    with jax.enable_x64(True):
-        for fn, args in captured.values():
-            out.update(spans.hlo_op_paths(fn.lower(*args).compile().as_text()))
-    return out
-
-
 def split(workload: str, seed: int, require_accelerator: bool = True,
           cell=None) -> dict:
-    import jax
-
-    from harness import spec
+    from harness import spans, spec
 
     cell = cell or spec.cell(workload)
     wl = cell["workload"]
     dev = bench_run.device_info(int(wl["chips"]), require_accelerator)
-    # the persistent cache's key ignores the scopes' metadata: a program
-    # cached from code without them would come back unscoped
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
+    restore_keys = spans.cache_keys_with_scopes()
     try:
         return _split(workload, seed, cell, dev)
     finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
+        restore_keys()
 
 
 def _split(workload, seed, cell, dev) -> dict:
     import jax
 
-    from harness import spans, sut, trace
+    from harness import spans, spec, sut, trace
     from harness import traffic as traffic_mod
     from repro.core.sim import jax_engine as je
     from repro.core.sim import telemetry
@@ -94,9 +62,7 @@ def _split(workload, seed, cell, dev) -> dict:
 
     # the arguments of each runner's last call, to read its compiled
     # module: the trace's op events name only the HLO instruction
-    runners = dict(je._RUNNERS)
-    captured = {}
-    je._RUNNERS.update({k: _Keep(fn, k, captured) for k, fn in runners.items()})
+    runners = spans.RunnerArgs(je)
 
     def timed_calls(ks):
         out = []
@@ -118,7 +84,7 @@ def _split(workload, seed, cell, dev) -> dict:
         traced_s = timed_calls(range(n + 1, 2 * n + 1))
         jax.profiler.stop_trace()
     finally:
-        je._RUNNERS.update(runners)
+        runners.close()
     per_call = 1 if tr["entry"] == "grid" else len(reals[0]["arrivals"])
     records = list(telemetry.CALLS)[-n * per_call:]
 
@@ -126,7 +92,9 @@ def _split(workload, seed, cell, dev) -> dict:
     path = trace.trace_file(trace_dir)
     calls, busy, op_ns = trace.read_trace(path, lines)
     red = trace.reduce(calls, busy, op_ns)
-    prog = spans.read_program_trace(path, lines, calls, _compiled_paths(je, captured))
+    prog = spans.read_program_trace(path, lines, calls,
+                                    spans.compiled_op_paths(runners.args),
+                                    stages=spans.split_scopes(spec.metric_scopes()))
     shutil.rmtree(trace_dir, ignore_errors=True)
 
     A, T = int(cfg["streams"]), int(cfg["ticks"])
